@@ -46,9 +46,8 @@ sys.path.insert(0, os.path.dirname(__file__))
 from common import emit, save  # noqa: E402
 
 
-def run_reshape_bench(args):
+def run_reshape_bench(args, devices):
     """In-memory RESHAPE vs checkpoint-stop-resume on one transition."""
-    import jax
     from repro.core.stop_resume import stop_resume_rescale
     from common import make_trainer  # noqa: E402 (benchmarks path)
 
@@ -56,7 +55,7 @@ def run_reshape_bench(args):
 
     def fresh():
         t = make_trainer(from_shape[0], batch=12, seq=64,
-                         devices=jax.devices(), seed=0,
+                         devices=devices, seed=0,
                          time_allowance_s=0.1)
         t.run(4)                    # settle the step-time EMA
         return t
@@ -94,12 +93,11 @@ def run_reshape_bench(args):
           f"{'OK' if results['reshape_beats_checkpoint'] else 'REGRESSION'}")
 
 
-def run_reshape_determinism_bench(args):
+def run_reshape_determinism_bench(args, devices):
     """Determinism mode of the reshape bench: with virtual workers on, a
     live RESHAPE (4,1) -> (2,2) mid-run must produce ZERO loss-trajectory
     divergence against the static run — bitwise, not tolerance-equal.
     Writes experiments/bench_reshape_determinism.json."""
-    import jax
     from common import make_trainer  # noqa: E402 (benchmarks path)
 
     nv, steps = 8, 10
@@ -107,7 +105,7 @@ def run_reshape_determinism_bench(args):
 
     def fresh():
         return make_trainer(from_shape[0], batch=8, seq=64,
-                            devices=jax.devices(), seed=0,
+                            devices=devices, seed=0,
                             virtual_workers=nv, time_allowance_s=0.1)
 
     static = fresh()
@@ -141,7 +139,7 @@ def run_reshape_determinism_bench(args):
     return 0 if results["bitwise_identical"] else 1
 
 
-def run_faults_bench(args):
+def run_faults_bench(args, devices):
     """Churn mode (``--faults``): replay a FaultPlan — a JSON revocation/
     kill trace or an inline ``random:`` spec — against the live workload,
     and run the SAME workload undisturbed as the baseline. Reports
@@ -158,8 +156,8 @@ def run_faults_bench(args):
     def run(faults):
         specs = parse_jobs(args.jobs, batch=12, seq=64, n_samples=1 << 10,
                            d_partitions=16, default_mp=args.model_parallel)
-        ex = ClusterExecutor(specs, make_policy(policy), faults=faults,
-                             compile_cache=args.compile_cache)
+        ex = ClusterExecutor(specs, make_policy(policy), devices=devices,
+                             faults=faults)
         t0 = time.monotonic()
         stats = ex.run(max_rounds=args.max_rounds)
         stats["wall_s"] = round(time.monotonic() - t0, 2)
@@ -215,7 +213,7 @@ def run_faults_bench(args):
     return 0 if churn["conserved"] else 1
 
 
-def run_serving_bench(args):
+def run_serving_bench(args, devices):
     """Serving-tier mode (``--serving-trace``): replay a diurnal request
     trace against one live ``ServingJob`` (real ``serve_batch`` waves,
     measured latency) sharing the pool with the ``--jobs`` training
@@ -242,9 +240,8 @@ def run_serving_bench(args):
              else AnalyticModel())
     policy = CrossTierPolicy(make_policy(policy_name))
     t0 = time.monotonic()
-    ex = ClusterExecutor(specs, policy, throughput_model=model,
-                         resched_every=2,
-                         compile_cache=args.compile_cache)
+    ex = ClusterExecutor(specs, policy, devices=devices,
+                         throughput_model=model, resched_every=2)
     stats = ex.run(max_rounds=args.max_rounds)
     wall = round(time.monotonic() - t0, 2)
     ex.close()
@@ -348,16 +345,18 @@ def main():
     ap.add_argument("--compile-cache", default=None, metavar="DIR")
     args = ap.parse_args()
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.devices import enable_compile_cache, pick_devices
+    devices = pick_devices(args.devices)
+    if args.compile_cache:
+        enable_compile_cache(args.compile_cache)
     if args.reshape:
-        return run_reshape_bench(args)
+        return run_reshape_bench(args, devices)
     if args.reshape_determinism:
-        return run_reshape_determinism_bench(args)
+        return run_reshape_determinism_bench(args, devices)
     if args.faults:
-        return run_faults_bench(args)
+        return run_faults_bench(args, devices)
     if args.serving_trace:
-        return run_serving_bench(args)
+        return run_serving_bench(args, devices)
     from repro.cluster import ClusterExecutor, make_policy
     from repro.launch.cluster import parse_jobs
     from repro.sched.throughput import AnalyticModel, MeasuredModel
@@ -373,10 +372,9 @@ def main():
             from repro.obs import Observability
             obs = Observability()
         t0 = time.monotonic()
-        ex = ClusterExecutor(specs, make_policy(name),
+        ex = ClusterExecutor(specs, make_policy(name), devices=devices,
                              throughput_model=model,
-                             profile_sweeps=args.profile_sweeps,
-                             compile_cache=args.compile_cache, obs=obs)
+                             profile_sweeps=args.profile_sweeps, obs=obs)
         stats = ex.run(max_rounds=args.max_rounds)
         ex.close()
         wall = time.monotonic() - t0

@@ -1,9 +1,10 @@
 """Shared helpers for the benchmark harness.
 
 Each benchmark reproduces one paper table/figure on the smoke-scale workload
-(CPU host devices). Wall-clock numbers are host measurements — valid for the
-paper's *relative* claims (EDL vs stop-resume ratios); TPU-absolute numbers
-live in the roofline analysis.
+on forced CPU host devices. Its wall-clock numbers time XLA's CPU backend:
+they bear on the paper's *relative* claims (EDL vs stop-resume ratios) at
+best, and say nothing about a TPU. No TPU number comes from these scripts;
+``chip_smoke.py`` is the only code here that has run on a chip.
 """
 from __future__ import annotations
 

@@ -27,13 +27,13 @@ from common import emit, save  # noqa: E402
 BUDGET_PCT = 2.0
 
 
-def run_cluster(args, obs):
+def run_cluster(args, devices, obs):
     from repro.cluster import ClusterExecutor, make_policy
     from repro.launch.cluster import parse_jobs
     specs = parse_jobs(args.jobs, batch=12, seq=64, n_samples=1 << 10,
                        d_partitions=16)
-    ex = ClusterExecutor(specs, make_policy("throughput"), obs=obs,
-                         compile_cache=args.compile_cache)
+    ex = ClusterExecutor(specs, make_policy("throughput"), devices=devices,
+                         obs=obs)
     t0 = time.monotonic()
     stats = ex.run(max_rounds=args.max_rounds)
     wall = time.monotonic() - t0
@@ -48,8 +48,10 @@ def main():
     ap.add_argument("--max-rounds", type=int, default=150)
     ap.add_argument("--compile-cache", default=None, metavar="DIR")
     args = ap.parse_args()
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.devices import enable_compile_cache, pick_devices
+    devices = pick_devices(args.devices)
+    if args.compile_cache:
+        enable_compile_cache(args.compile_cache)
 
     from repro.obs import Observability
 
@@ -58,9 +60,9 @@ def main():
     trace = os.path.join(tmp, "trace.json")
 
     # the same live workload, sinkless vs fully instrumented
-    ex_off, stats_off, wall_off = run_cluster(args, obs=None)
+    ex_off, stats_off, wall_off = run_cluster(args, devices, obs=None)
     obs = Observability(telemetry_out=telemetry, trace_out=trace)
-    ex_on, stats_on, wall_on = run_cluster(args, obs=obs)
+    ex_on, stats_on, wall_on = run_cluster(args, devices, obs=obs)
     obs.close()
 
     rounds = max(1, stats_off["rounds"])

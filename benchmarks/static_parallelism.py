@@ -14,25 +14,19 @@ def plain_loop_throughput(p: int, steps: int, *, batch=8, seq=64) -> float:
     """Horovod-analogue: static data-parallel jit loop, pre-sharded data."""
     import jax
     from repro.configs import get_config
+    from repro.core.elastic_runtime import jit_step
     from repro.launch.mesh import make_mesh
     from repro.optim import adamw
-    from repro.training.step import batch_sharding, init_train_state, \
-        make_train_step, state_sharding
-    from repro.configs.base import InputShape, input_specs
+    from repro.training.step import init_train_state
     cfg = get_config("edl-paper", smoke=True)
     opt = adamw(1e-3)
     mesh = make_mesh(p, 1)
-    st_sh = state_sharding(cfg, mesh, opt)
-    shape = InputShape("b", seq, batch, "train")
-    b_sh = batch_sharding(cfg, mesh, input_specs(cfg, shape))
     # AOT-compiled executable — the identical execution path EDL uses, so
     # the measured delta is exactly the elasticity layer's overhead
-    from repro.core.elastic_runtime import _abstract_state
-    with mesh:
-        fn = jax.jit(make_train_step(cfg, opt), in_shardings=(st_sh, b_sh),
-                     out_shardings=(st_sh, None)).lower(
-                         _abstract_state(cfg, opt),
-                         input_specs(cfg, shape)).compile()
+    fn, args, st_sh, b_sh = jit_step(cfg, opt, mesh, seq_len=seq,
+                                     global_batch=batch)
+    with jax.set_mesh(mesh):
+        fn = fn.lower(*args).compile()
     state = jax.device_put(init_train_state(cfg, opt, jax.random.PRNGKey(0)),
                            st_sh)
     bt = {"tokens": np.random.randint(0, cfg.vocab, (batch, seq), np.int32),

@@ -32,9 +32,10 @@ when it reshapes mp=auto ones).
 """
 import sys
 
-# repro.launch.cluster forces the multi-device host platform BEFORE jax
-# loads, parses the job grammar, runs the executor, and prints the event
-# timeline — this example is the human-facing entry point for it.
+# repro.launch.cluster picks the devices (on the CPU it forces a
+# multi-device host platform before JAX starts), parses the job grammar,
+# runs the executor, and prints the event timeline — this example is the
+# human-facing entry point for it.
 from repro.launch.cluster import main
 
 if __name__ == "__main__":

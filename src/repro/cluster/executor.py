@@ -77,23 +77,6 @@ from repro.core.scaling import Busy, Phase
 from repro.sched.base import normalize_target
 
 
-def enable_compile_cache(path: str) -> str:
-    """Opt-in persistent XLA compilation cache: repeated topologies skip
-    recompilation across rounds, runs, and processes — the first step
-    toward unserializing background context-preps on small hosts (the
-    in-process exec-handle cache only helps within one trainer's life;
-    this survives preempt/re-admit teardowns and whole reruns). Thresholds
-    drop to zero because smoke-scale step functions compile in well under
-    the default 1 s minimum."""
-    import os
-    import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    return str(path)
-
-
 def default_trainer_factory(spec: JobSpec, devices: list):
     """Build the live engine owning exactly ``devices``: a real
     ElasticTrainer for training specs (a whole number of mp-sized groups,
@@ -106,13 +89,22 @@ def default_trainer_factory(spec: JobSpec, devices: list):
     from repro.core import ElasticTrainer
     from repro.optim import adamw
     cfg = get_config(spec.arch, smoke=True)
+    # no time allowance: the tenants share this process, so a switch needs
+    # no lead time to reach its workers and commits at the first boundary
+    # after its prep lands. An allowance in seconds is ceil(allowance /
+    # step time) steps, which grows as steps get shorter: on an
+    # accelerator it outlasted short tenants.
     return ElasticTrainer(
         cfg, global_batch=spec.global_batch, seq_len=spec.seq_len,
         init_parallelism=len(devices) // spec.model_parallel,
         model_parallel=spec.model_parallel, optimizer=adamw(spec.lr),
         n_samples=spec.n_samples, d_partitions=spec.d_partitions,
         job_handle=spec.name, seed=spec.seed, devices=devices,
-        virtual_workers=spec.virtual_workers, time_allowance_s=0.1)
+        virtual_workers=spec.virtual_workers, time_allowance_s=0.0)
+
+
+class DeviceLeak(RuntimeError):
+    """Device conservation broke: the pool lost or duplicated a device."""
 
 
 class DiskCheckpointer:
@@ -210,7 +202,6 @@ class ClusterExecutor:
                  checkpointer=None, throughput_model=None,
                  profile_sweeps: bool = False, profile_steps: int = 3,
                  profile_ttl: float | None = None,
-                 compile_cache: str | None = None,
                  faults=None, ckpt_max_retries: int = 3,
                  obs=None):
         # set FIRST: close()/__del__ must be safe even if construction
@@ -220,8 +211,6 @@ class ClusterExecutor:
         # event is mirrored onto its typed bus, committed switches become
         # span trees, and the round loop drives its metrics sampling
         self.obs = obs
-        if compile_cache:
-            enable_compile_cache(compile_cache)
         if devices is None:
             import jax
             devices = jax.devices()
@@ -1022,9 +1011,10 @@ class ClusterExecutor:
                    if j.jid not in self.checkpointing)
         pending_ckpt = sum(j.devices_held
                            for j in self.checkpointing.values())
-        assert live + pending_ckpt + len(self.free) == self.n_gpus, \
-            (f"device leak: {live} live + {pending_ckpt} checkpointing "
-             f"+ {len(self.free)} free != {self.n_gpus}")
+        if live + pending_ckpt + len(self.free) != self.n_gpus:
+            raise DeviceLeak(
+                f"device leak: {live} live + {pending_ckpt} checkpointing "
+                f"+ {len(self.free)} free != {self.n_gpus}")
 
     # -------------------------------------------------------------- driver
     def run(self, *, max_rounds: int = 10_000) -> dict:
@@ -1203,7 +1193,7 @@ class ClusterExecutor:
                 if self.recovery_latencies else None),
             "faults_pending": (len(self.injector.pending)
                                if self.injector is not None else 0),
-            "conserved": True,      # run() asserts it every round
+            "conserved": True,      # run() raises DeviceLeak otherwise
             "compile_service": (self.compile_service.stats()
                                 if self.compile_service is not None
                                 else None),

@@ -24,7 +24,6 @@ from typing import Callable
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.coordination import CoordinationStore
 from repro.core.election import LeaderElection
@@ -40,6 +39,11 @@ from repro.training.step import batch_sharding, init_train_state, \
 
 TIME_ALLOWANCE_S = 0.5      # paper's T_a
 EXEC_CACHE_MAX = 8          # compiled topologies retained per job (LRU)
+
+
+class PrepFailed(RuntimeError):
+    """The background context prep (the compile) of a scaling operation
+    failed; the operation was dropped."""
 
 
 @dataclasses.dataclass
@@ -126,8 +130,8 @@ class ElasticTrainer:
         self.store = store or CoordinationStore()
         self.use_aot = use_aot
         self.seed = seed
-        # paper default 500 ms; cluster executor shrinks it for smoke-scale
-        # jobs whose whole lifetime is a few seconds
+        # paper default 500 ms; the cluster executor, whose tenants share
+        # one process, uses 0 (commit at the first boundary after prep)
         self.time_allowance_s = time_allowance_s
         # adjustment-overhead pipeline: when a CompileService is attached
         # (ctor arg, or set by the cluster executor after launch), context
@@ -184,7 +188,7 @@ class ElasticTrainer:
 
         self.exec = self._build_exec(init_parallelism)
         key = jax.random.PRNGKey(seed)
-        with self.exec.mesh:
+        with jax.set_mesh(self.exec.mesh):
             state = init_train_state(cfg, self.optimizer, key)
         self.state = jax.device_put(state, self.exec.state_shardings)
 
@@ -289,30 +293,13 @@ class ElasticTrainer:
                 self._exec_cache[key] = self._exec_cache.pop(key)  # LRU
                 return cached
         mesh = make_mesh(p, mp, devices=np.array(devs[: p * mp]))
-        st_sh = state_sharding(self.cfg, mesh, self.optimizer)
-        from repro.configs.base import InputShape, input_specs
-        shape = InputShape("rt", self.seq_len, self.global_batch, "train")
-        specs = input_specs(self.cfg, shape)
-        specs.pop("cache", None)
-        b_sh = batch_sharding(self.cfg, mesh, specs)
-        # virtual mode builds the deterministic shard_map step for THIS
-        # mesh shape; the step math (per-vw slices, tree reduction, per-vw
-        # RNG) is a function of n_virtual alone, so every shape computes
-        # bitwise-identical updates
-        fn = make_train_step(self.cfg, self.optimizer,
-                             n_virtual=self.n_virtual, mesh=mesh,
-                             global_batch=self.global_batch, seed=self.seed)
+        step_fn, abstract_args, st_sh, b_sh = jit_step(
+            self.cfg, self.optimizer, mesh, seq_len=self.seq_len,
+            global_batch=self.global_batch, n_virtual=self.n_virtual,
+            seed=self.seed)
         if self.use_aot:
-            with mesh:
-                compiled = jax.jit(
-                    fn, in_shardings=(st_sh, b_sh),
-                    out_shardings=(st_sh, None)).lower(
-                        _abstract_state(self.cfg, self.optimizer), specs
-                    ).compile()
-            step_fn = compiled
-        else:
-            step_fn = jax.jit(fn, in_shardings=(st_sh, b_sh),
-                              out_shardings=(st_sh, None))
+            with jax.set_mesh(mesh):
+                step_fn = step_fn.lower(*abstract_args).compile()
         handle = ExecHandle(p, mp, mesh, step_fn, st_sh, b_sh)
         with self._exec_lock:
             handle = self._exec_cache.setdefault(key, handle)
@@ -369,7 +356,15 @@ class ElasticTrainer:
         return batch
 
     def step(self) -> dict | None:
-        """One synchronous mini-batch across the current topology."""
+        """One synchronous mini-batch across the current topology. Raises
+        ``PrepFailed`` (and drops the operation) when the background context
+        prep of the scaling operation in flight failed."""
+        if self._prep_error is not None:
+            err, self._prep_error = self._prep_error, None
+            op = self.controller.plan.record.op
+            self.controller.abort()
+            raise PrepFailed(f"{self.job_handle}: background context prep "
+                             f"for {op} failed: {err!r}") from err
         t0 = time.monotonic()
         batch = self._assemble_batch()
         if batch is None:
@@ -564,6 +559,12 @@ class ElasticTrainer:
         def prepare():
             finish(self._build_exec(target_p, target_mp))
 
+        def prepare_in_background():
+            try:
+                prepare()
+            except Exception as e:      # raised by the next step()
+                self._prep_error = e
+
         if block:
             prepare()
             # commit at the next boundary manually
@@ -582,10 +583,9 @@ class ElasticTrainer:
             from repro.core.compile_service import DONE, PRIO_COMMITTED
 
             def on_ticket(t):
-                if t.state != DONE:
-                    # parity with the thread path's failure mode: the op
-                    # sticks in PREPARING, error kept for inspection
-                    self._prep_error = t.error
+                if t.state != DONE:     # raised by the next step()
+                    self._prep_error = t.error or RuntimeError(
+                        f"context prep ticket {key} ended {t.state}")
                     return
                 finish(t.value)
 
@@ -596,7 +596,8 @@ class ElasticTrainer:
                 priority=PRIO_COMMITTED, owner=self.job_handle)
             self._prep_ticket.add_done_callback(on_ticket)
             return None
-        self._prep_thread = threading.Thread(target=prepare, daemon=True)
+        self._prep_thread = threading.Thread(target=prepare_in_background,
+                                             daemon=True)
         self._prep_thread.start()
         return None
 
@@ -822,6 +823,29 @@ class ElasticTrainer:
     def throughput(self, last_n: int = 20) -> float:
         xs = self.throughput_log[-last_n:]
         return float(np.mean([t for _, _, t in xs])) if xs else 0.0
+
+
+def jit_step(cfg, optimizer, mesh, *, seq_len: int, global_batch: int,
+             n_virtual: int = 0, seed: int = 0):
+    """The jitted train step of a job on ``mesh``: returns ``(step,
+    abstract_args, state_shardings, batch_shardings)``, where
+    ``step.lower(*abstract_args)`` needs no arrays, so ``mesh`` may hold
+    described devices (a compile for a chip that is not attached). Virtual
+    mode builds the deterministic shard_map step for THIS mesh shape; its
+    math (per-vw slices, tree reduction, per-vw RNG) is a function of
+    ``n_virtual`` alone, so every shape computes bitwise-identical
+    updates."""
+    from repro.configs.base import InputShape, input_specs
+    st_sh = state_sharding(cfg, mesh, optimizer)
+    specs = input_specs(cfg, InputShape("rt", seq_len, global_batch,
+                                        "train"))
+    specs.pop("cache", None)
+    b_sh = batch_sharding(cfg, mesh, specs)
+    fn = make_train_step(cfg, optimizer, n_virtual=n_virtual, mesh=mesh,
+                         global_batch=global_batch, seed=seed)
+    step = jax.jit(fn, in_shardings=(st_sh, b_sh),
+                   out_shardings=(st_sh, None))
+    return step, (_abstract_state(cfg, optimizer), specs), st_sh, b_sh
 
 
 def _abstract_state(cfg, optimizer):

@@ -85,7 +85,7 @@ def _consistent(trainer, dead, checkpoint_dir) -> ScalingRecord:
     handle = trainer._build_exec(target_p)
     rec.t_prep_end = time.monotonic()
     rec.t_switch_start = rec.t_prep_end
-    with handle.mesh:
+    with jax.set_mesh(handle.mesh):
         template = init_train_state(trainer.cfg, trainer.optimizer,
                                     jax.random.PRNGKey(0))
     restored, meta = load_checkpoint(checkpoint_dir,

@@ -94,7 +94,7 @@ def resume_from_checkpoint(trainer, checkpoint_dir: str) -> dict:
     restored pipeline."""
     from repro.reshape import StateSpec, apply_plan, plan_reshard
     from repro.training.step import init_train_state
-    with trainer.exec.mesh:
+    with jax.set_mesh(trainer.exec.mesh):
         template = init_train_state(trainer.cfg, trainer.optimizer,
                                     jax.random.PRNGKey(0))
     restored, meta = load_checkpoint(checkpoint_dir,

@@ -85,12 +85,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_bhld(q, k, v, *, causal: bool = True, window: int = 0,
                          scale: float | None = None, block_q: int = 128,
                          block_k: int = 128, kv_len: int | None = None,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """q: [B, Hq, Lq, D]; k/v: [B, Hkv, Lk, D] with Hq % Hkv == 0.
 
     Lq/Lk must be multiples of block_q/block_k (ops.py pads). ``kv_len``
-    masks padding at the tail of k/v.
+    masks padding at the tail of k/v. ``interpret`` defaults to the
+    backend: compiled on the TPU, interpreted (jnp) everywhere else.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, Hq, Lq, D = q.shape
     _, Hkv, Lk, _ = k.shape
     assert Hq % Hkv == 0 and Lq % block_q == 0 and Lk % block_k == 0

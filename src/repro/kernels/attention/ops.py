@@ -1,9 +1,9 @@
 """jit'd wrapper around the Pallas flash-attention kernel.
 
 Accepts the model's grouped layout [B, Hkv, G, L, D], pads sequence lengths
-to block multiples, dispatches to the kernel (interpret=True on CPU — the
-kernel body runs in Python for validation; on TPU set interpret=False), and
-restores the layout.
+to block multiples, dispatches to the kernel (compiled on the TPU; elsewhere
+interpreted, so the kernel body runs as jnp ops for validation on the CPU),
+and restores the layout.
 """
 from __future__ import annotations
 
@@ -11,8 +11,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.attention.kernel import flash_attention_bhld
-
-INTERPRET = True    # CPU container: validate kernels in interpret mode
 
 
 def _pad_to(x, mult: int, axis: int):
@@ -37,6 +35,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     vh, _ = _pad_to(v, block_k, 2)
     out = flash_attention_bhld(qh, kh, vh, causal=causal, window=window,
                                scale=scale, block_q=block_q, block_k=block_k,
-                               kv_len=Lk0, interpret=INTERPRET)
+                               kv_len=Lk0)
     out = out[:, :, :Lq0]
     return out.reshape(B, Hkv, G, Lq0, out.shape[-1])

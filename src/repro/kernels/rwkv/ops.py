@@ -7,7 +7,6 @@ import jax.numpy as jnp
 
 from repro.kernels.rwkv.kernel import wkv6_bhld
 
-INTERPRET = True
 CHUNK = 32
 
 
@@ -23,7 +22,6 @@ def wkv6(r, k, v, logw, u, s0, *, chunk: int = CHUNK):
         k = jnp.pad(k, pw)
         v = jnp.pad(v, pw)
         logw = jnp.pad(logw, pw)        # logw=0 -> decay 1: state unchanged
-    y, sT = wkv6_bhld(tr(r), tr(k), tr(v), tr(logw), u, s0, chunk=chunk,
-                      interpret=INTERPRET)
+    y, sT = wkv6_bhld(tr(r), tr(k), tr(v), tr(logw), u, s0, chunk=chunk)
     y = tr(y)[:, :L] if pad else tr(y)
     return y, sT

@@ -1,23 +1,3 @@
-import argparse
-import os
-import sys
-
-
-def _preparse_devices() -> int:
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int,
-                    default=int(os.environ.get("EDL_DEVICES", "4")))
-    ns, _ = ap.parse_known_args()
-    return ns.devices
-
-
-_N_DEV = _preparse_devices()
-if "--xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               f" --xla_force_host_platform_device_count="
-                               f"{_N_DEV}")
-
 """Multi-tenant cluster driver (end-to-end example + integration target).
 
 Runs N concurrent elastic jobs on a shared device pool under a pluggable
@@ -71,8 +51,16 @@ sched.workload's trace generators (keys: trace=philly|synthetic, seed,
 jobs, steps=LO:HI, mp=1:2 — colon-separated model-parallel degrees drawn
 per job for a mixed-mp population; the degree ``auto`` draws
 reshape-able tenants).
+
+``--devices N`` takes the first N devices of JAX's backend; on the CPU
+N host devices are emulated. The driver exits
+non-zero when the backend has fewer, when device conservation breaks, or
+when a job does not finish within ``--max-rounds``.
 """
+import argparse
 import json
+import os
+import sys
 import time
 
 
@@ -200,7 +188,7 @@ def parse_workload(text: str, *, devices: int, batch: int, seq: int,
         n_samples=n_samples, d_partitions=d_partitions)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--jobs", default="a=vgg19:3:25@0,b=resnet50:1:30@0,"
                                       "c=googlenet:1:15@6")
@@ -230,9 +218,11 @@ def main(argv=None):
                          "once its measured curve is this many rounds old "
                          "(default: sweep each job at most once)")
     ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation-cache directory: "
-                         "repeated topologies skip recompilation across "
-                         "rounds and runs")
+                    help="persistent JAX compilation-cache directory "
+                         "(JAX_COMPILATION_CACHE_DIR wins when set; "
+                         "default .jax_cache/ in the checkout): repeated "
+                         "topologies skip recompilation across rounds and "
+                         "runs")
     ap.add_argument("--prefetch-shapes", action="store_true",
                     help="speculatively compile each job's likely-next "
                          "shapes (sched.base.likely_next_shapes) on idle "
@@ -267,7 +257,8 @@ def main(argv=None):
                     help="serve the metrics registry as Prometheus text "
                          "on 127.0.0.1:PORT while the run is live "
                          "(stdlib HTTP; 0 picks an ephemeral port)")
-    ap.add_argument("--devices", type=int, default=_N_DEV)
+    ap.add_argument("--devices", type=int,
+                    default=int(os.environ.get("EDL_DEVICES", "4")))
     ap.add_argument("--batch", type=int, default=12)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--n-samples", type=int, default=1 << 10)
@@ -275,9 +266,16 @@ def main(argv=None):
     ap.add_argument("--resched-every", type=int, default=3)
     ap.add_argument("--max-rounds", type=int, default=500)
     ap.add_argument("--json", action="store_true", help="machine output")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    from repro.cluster import ClusterExecutor, make_policy
+
+def run(args, devices, *, checkpoint_root: str | None = None) -> dict:
+    """Run the tenants of ``args`` on ``devices`` to completion (or
+    ``--max-rounds``) and return the executor's stats. Preemption
+    checkpoints go under ``checkpoint_root`` (default: the system's
+    temporary directory). Raises ``DeviceLeak`` the round device
+    conservation breaks."""
+    from repro.cluster import ClusterExecutor, DiskCheckpointer, make_policy
     from repro.sched.throughput import AnalyticModel, MeasuredModel
 
     if args.workload:
@@ -315,15 +313,16 @@ def main(argv=None):
             print(f"metrics: http://127.0.0.1:{obs.prom_port}/metrics",
                   file=sys.stderr)
     t0 = time.monotonic()
-    ex = ClusterExecutor(specs, policy, resched_every=args.resched_every,
+    ex = ClusterExecutor(specs, policy, devices=devices,
+                         resched_every=args.resched_every,
                          throughput_model=model,
                          profile_sweeps=args.profile_sweeps,
                          profile_ttl=args.profile_ttl,
-                         compile_cache=args.compile_cache,
                          prefetch_shapes=args.prefetch_shapes,
                          compile_workers=args.compile_workers,
                          serialize_prep=args.serialize_prep or None,
-                         faults=faults, obs=obs)
+                         faults=faults, obs=obs,
+                         checkpointer=DiskCheckpointer(checkpoint_root))
     try:
         stats = ex.run(max_rounds=args.max_rounds)
     finally:
@@ -339,12 +338,39 @@ def main(argv=None):
         if args.metrics_out:
             print(f"telemetry written to {args.metrics_out} "
                   f"({obs.bus.emitted} event(s))", file=sys.stderr)
+    return stats
 
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.cluster import DeviceLeak
+    from repro.launch.devices import describe, enable_compile_cache, \
+        pick_devices
+    devices = pick_devices(args.devices)
+    enable_compile_cache(args.compile_cache)
+    try:
+        stats = run(args, devices)
+    except DeviceLeak as e:
+        print(f"device conservation: LEAK ({e})", file=sys.stderr)
+        return 1
+    stats["device"] = describe(devices)
     if args.json:
         print(json.dumps(stats))
-        return 0
+    else:
+        _print_report(args, stats)
+    unfinished = [j["name"] for j in stats["jobs"]
+                  if j["state"] != "finished"]
+    if unfinished:
+        print(f"unfinished after {stats['rounds']} round(s): {unfinished}",
+              file=sys.stderr)
+        return 1
+    return 0
+
+
+def _print_report(args, stats):
     print(f"policy={args.policy} model={args.throughput_model} "
-          f"devices={ex.n_gpus} "
+          f"devices={stats['n_gpus']} ({stats['device']['platform']} "
+          f"{stats['device']['kind']}) "
           f"rounds={stats['rounds']} wall={stats['wall_s']}s")
     print(f"{'job':>8s} {'profile':>10s} {'req_p':>5s} {'mp':>3s} "
           f"{'steps':>5s} {'jct':>7s} {'loss':>8s}")
@@ -386,7 +412,6 @@ def main(argv=None):
         print(f"serving: {stats['rounds_served']} round(s) served, "
               f"{stats['slo_breaches']} SLO breach(es), p99 attainment "
               + (f"{att:.1%}" if att is not None else "-"))
-    return 0
 
 
 if __name__ == "__main__":

@@ -41,14 +41,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config, input_specs
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, \
-    make_production_mesh
+from repro.launch.mesh import make_production_mesh, peaks_for
 from repro.models import model as M
 from repro.models.blocks import scan_plan
 from repro.optim import adamw
 from repro.training.step import batch_sharding, cache_sharding, \
     make_train_step, params_sharding, state_shape_structs, state_sharding
 
+TARGET_KIND = "TPU v5 lite"     # the chip of make_production_mesh's pod
 COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
                     "all-to-all", "collective-permute")
 COLLECTIVE_RE = re.compile(
@@ -102,7 +102,7 @@ def _lower(cfg, shape, mesh):
         st = state_shape_structs(cfg, optimizer)
         st_sh = state_sharding(cfg, mesh, optimizer)
         b_sh = batch_sharding(cfg, mesh, specs)
-        with mesh:
+        with jax.set_mesh(mesh):
             return jax.jit(fn, in_shardings=(st_sh, b_sh),
                            out_shardings=(st_sh, None)).lower(st, specs)
     p = M.param_shape_structs(cfg)
@@ -110,13 +110,13 @@ def _lower(cfg, shape, mesh):
     if shape.mode == "prefill":
         fn = lambda params, batch: M.prefill(cfg, params, batch)
         b_sh = batch_sharding(cfg, mesh, specs)
-        with mesh:
+        with jax.set_mesh(mesh):
             return jax.jit(fn, in_shardings=(p_sh, b_sh)).lower(p, specs)
     fn = lambda params, batch, cache: M.serve_step(cfg, params, batch, cache)
     cache_specs_ = specs.pop("cache")
     c_sh = cache_sharding(cfg, mesh, shape.global_batch, shape.seq_len)
     b_sh = batch_sharding(cfg, mesh, specs)
-    with mesh:
+    with jax.set_mesh(mesh):
         return jax.jit(fn, in_shardings=(p_sh, b_sh, c_sh),
                        out_shardings=(None, c_sh)).lower(
                            p, specs, cache_specs_)
@@ -206,9 +206,12 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         coll_bytes = {k: c1["coll_bytes"][k] + (n_periods - 1) *
                       (c2["coll_bytes"][k] - c1["coll_bytes"][k])
                       for k in c1["coll_bytes"]}
-        t_compute = tot["flops"] / PEAK_FLOPS_BF16      # per-device numbers
-        t_memory = tot["bytes"] / HBM_BW
-        t_coll = tot["coll"] / ICI_BW
+        # per-device numbers against the production pod's chip, which this
+        # CPU-hosted dry-run only describes
+        peaks = peaks_for(TARGET_KIND)
+        t_compute = tot["flops"] / peaks.flops_bf16
+        t_memory = tot["bytes"] / peaks.hbm_bw
+        t_coll = tot["coll"] / peaks.ici_bw
         n_params = cfg.param_count()
         n_active = cfg.active_param_count()
         tokens = shape.global_batch * (shape.seq_len

@@ -6,13 +6,37 @@ device query; see launch/dryrun.py).
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import numpy as np
 
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks, for roofline and utilization figures."""
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # bytes/s
+    ici_bw: float               # bytes/s per link
+    source: str
+
+
+# keyed by ``jax.Device.device_kind``
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9,
+        ici_bw=50e9,            # 1,600 Gbit/s over four links
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peaks of one chip kind; a kind without published peaks here is
+    an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,23 +1,3 @@
-import argparse
-import os
-import sys
-
-
-def _preparse_devices() -> int:
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int,
-                    default=int(os.environ.get("EDL_DEVICES", "8")))
-    ns, _ = ap.parse_known_args()
-    return ns.devices
-
-
-_N_DEV = _preparse_devices()
-if "--xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               f" --xla_force_host_platform_device_count="
-                               f"{_N_DEV}")
-
 """Elastic training driver (end-to-end example + integration-test target).
 
 Trains an elastic job under a scaling schedule and reports metrics + scaling
@@ -44,12 +24,20 @@ trajectory on the new (dp, mp).
 ``--virtual-workers K`` (or ``auto``) turns on deterministic elasticity:
 the loss trajectory (reported in the JSON ``losses`` field) is
 bitwise-identical across every parallelism and every elastic schedule.
+
+``--devices N`` takes the first N devices of JAX's backend; on the CPU
+N host devices are emulated. The run fails when the
+backend has fewer, when a background compile fails, or when the
+``EDL_WALL_LIMIT_S`` deadline (default 600 s) passes before it ends.
 """
+import argparse
 import json
+import os
+import sys
 import time
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="edl-paper")
     ap.add_argument("--smoke", action="store_true")
@@ -58,7 +46,8 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--init-p", type=int, default=2)
     ap.add_argument("--model-parallel", type=int, default=1)
-    ap.add_argument("--devices", type=int, default=_N_DEV)
+    ap.add_argument("--devices", type=int,
+                    default=int(os.environ.get("EDL_DEVICES", "8")))
     ap.add_argument("--schedule", default="")
     ap.add_argument("--n-samples", type=int, default=1 << 14)
     ap.add_argument("--d-partitions", type=int, default=64)
@@ -70,15 +59,30 @@ def main(argv=None):
                          "deterministic elasticity: bitwise-identical "
                          "trajectories at every (dp, mp)")
     args = ap.parse_args(argv)
-    vw = args.virtual_workers
-    if vw is not None and vw != "auto":
-        vw = int(vw)
+    if args.virtual_workers not in (None, "auto"):
+        args.virtual_workers = int(args.virtual_workers)
+    return args
 
-    import jax  # noqa: E402  (after XLA_FLAGS)
+
+def build_trainer(args, devices):
+    """The elastic job the driver trains, on ``devices``."""
     from repro.configs import get_config
-    from repro.core import ElasticTrainer, stop_resume_rescale
-    from repro.core.failure import fail_worker, recover
+    from repro.core import ElasticTrainer
     from repro.optim import adamw
+    cfg = get_config(args.arch, smoke=args.smoke)
+    return ElasticTrainer(
+        cfg, global_batch=args.batch, seq_len=args.seq,
+        init_parallelism=args.init_p, model_parallel=args.model_parallel,
+        optimizer=adamw(args.lr), n_samples=args.n_samples,
+        d_partitions=args.d_partitions, seed=args.seed,
+        virtual_workers=args.virtual_workers, devices=devices)
+
+
+def train(trainer, args, log=print) -> dict:
+    """Run ``args.steps`` steps under ``args.schedule``, then drain pending
+    schedule entries and any in-flight switch. Returns the summary."""
+    from repro.core import stop_resume_rescale
+    from repro.core.failure import fail_worker, recover
 
     def _apply_op(trainer, opn, n):
         if opn == "out":
@@ -108,14 +112,8 @@ def main(argv=None):
                 trainer.inject_worker_failure(wid)
         elif opn == "kill_leader":
             trainer.inject_worker_failure(trainer.leader_id)
-
-    cfg = get_config(args.arch, smoke=args.smoke)
-    trainer = ElasticTrainer(
-        cfg, global_batch=args.batch, seq_len=args.seq,
-        init_parallelism=args.init_p, model_parallel=args.model_parallel,
-        optimizer=adamw(args.lr), n_samples=args.n_samples,
-        d_partitions=args.d_partitions, seed=args.seed,
-        virtual_workers=vw)
+        else:
+            raise ValueError(f"unknown schedule op {opn!r}")
 
     schedule: dict[int, list[tuple[str, int]]] = {}
     if args.schedule:
@@ -125,60 +123,67 @@ def main(argv=None):
             schedule.setdefault(int(at), []).append((opn, int(n)))
 
     consumed_ids: list = []
-    log = print if not args.json else (lambda *a, **k: None)
     t0 = time.monotonic()
     from repro.core.scaling import Busy, Phase
-    deadline = t0 + float(os.environ.get("EDL_WALL_LIMIT_S", "600"))
+    limit_s = float(os.environ.get("EDL_WALL_LIMIT_S", "600"))
 
     def pending_ops():
         return any(k >= trainer.step_idx and v for k, v in schedule.items())
 
     # main loop runs to --steps, then drains: pending (retried) schedule
     # entries and any in-flight background scaling commit before exit
-    while (trainer.step_idx < args.steps or pending_ops()
-           or trainer.controller.phase is not Phase.IDLE):
-        if time.monotonic() > deadline:
-            break
-        for opn, n in schedule.pop(trainer.step_idx, []):
-            try:
-                _apply_op(trainer, opn, n)
-            except Busy:    # paper: scheduler retries after a delay
-                schedule.setdefault(trainer.step_idx + 5, []).append(
-                    (opn, n))
-        m = trainer.step()
-        # automatic dead-worker recovery: the leader's liveness view
-        # (missed gradient-syncs) drives a stop-free scale-in; training
-        # continues through the background prep and the trajectory is
-        # bitwise-preserved under --virtual-workers
-        dead = trainer.dead_workers()
-        if dead and trainer.controller.phase is Phase.IDLE:
-            try:
-                trainer.handle_failure(dead)
-            except (Busy, ValueError):
-                pass    # retried next step / no feasible survivor shape
-        if m is None:
-            if trainer.controller.phase is Phase.SCHEDULED:
-                trainer._commit_switch()
-            continue
-        consumed_ids.append(trainer._last_sample_ids)
-        # straggler mitigation: leader removes flagged workers (§5.2)
-        for wid in getattr(trainer, "_flagged_stragglers", []):
-            trainer.injected_delay.pop(wid, None)
-            try:
-                trainer.scale_in(1, victims=[wid])
-            except Exception:
-                pass
-        if m["step"] % 20 == 0:
-            log(f"step {m['step']:5d} p={m['p']} loss={m['loss']:.4f} "
-                f"thr={trainer.throughput():.1f} samp/s")
+    try:
+        while (trainer.step_idx < args.steps or pending_ops()
+               or trainer.controller.phase is not Phase.IDLE):
+            if time.monotonic() - t0 > limit_s:
+                raise TimeoutError(
+                    f"EDL_WALL_LIMIT_S={limit_s:g} s passed at step "
+                    f"{trainer.step_idx} of {args.steps} (scaling phase "
+                    f"{trainer.controller.phase.value})")
+            for opn, n in schedule.pop(trainer.step_idx, []):
+                try:
+                    _apply_op(trainer, opn, n)
+                except Busy:    # paper: scheduler retries after a delay
+                    schedule.setdefault(trainer.step_idx + 5, []).append(
+                        (opn, n))
+            m = trainer.step()
+            # automatic dead-worker recovery: the leader's liveness view
+            # (missed gradient-syncs) drives a stop-free scale-in; training
+            # continues through the background prep and the trajectory is
+            # bitwise-preserved under --virtual-workers
+            dead = trainer.dead_workers()
+            if dead and trainer.controller.phase is Phase.IDLE:
+                try:
+                    trainer.handle_failure(dead)
+                except (Busy, ValueError):
+                    pass    # retried next step / no feasible survivor shape
+            if m is None:
+                if trainer.controller.phase is Phase.SCHEDULED:
+                    trainer._commit_switch()
+                continue
+            consumed_ids.append(trainer._last_sample_ids)
+            # straggler mitigation: leader removes flagged workers (§5.2)
+            for wid in getattr(trainer, "_flagged_stragglers", []):
+                trainer.injected_delay.pop(wid, None)
+                try:
+                    trainer.scale_in(1, victims=[wid])
+                except Busy:
+                    pass    # flagged again after the op in flight commits
+            if m["step"] % 20 == 0:
+                log(f"step {m['step']:5d} p={m['p']} loss={m['loss']:.4f} "
+                    f"thr={trainer.throughput():.1f} samp/s")
+    finally:
+        # a compile still running in a daemon thread at interpreter exit
+        # aborts the process
+        trainer.join_prep(120)
     wall = time.monotonic() - t0
 
     import numpy as np
     ids = np.concatenate(consumed_ids) if consumed_ids else np.array([])
     epochs_done = trainer.pipeline.epoch
     summary = {
-        "arch": cfg.name, "steps": trainer.step_idx, "final_p": trainer.p,
-        "wall_s": round(wall, 2),
+        "arch": trainer.cfg.name, "steps": trainer.step_idx,
+        "final_p": trainer.p, "wall_s": round(wall, 2),
         "final_loss": trainer.metrics_log[-1]["loss"],
         "first_loss": trainer.metrics_log[0]["loss"],
         # the full per-step trajectory: with --virtual-workers this is the
@@ -200,6 +205,19 @@ def main(argv=None):
         summary["epoch0_exactly_once"] = bool(
             sorted(first_epoch.tolist()) ==
             list(range(trainer.dataset.n_samples)))
+    return summary
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    from repro.launch.devices import describe, enable_compile_cache, \
+        pick_devices
+    devices = pick_devices(args.devices)
+    enable_compile_cache()
+    trainer = build_trainer(args, devices)
+    summary = train(trainer, args,
+                    log=(lambda *a, **k: None) if args.json else print)
+    summary["device"] = describe(devices)
     print(json.dumps(summary) if args.json else
           json.dumps(summary, indent=1))
     return 0

@@ -127,22 +127,11 @@ def constrain(x: jax.Array, logical_axes: Sequence[str | None],
 
 
 def get_abstract_mesh_or_none():
-    """The mesh visible at trace time: either the jax.set_mesh abstract-mesh
-    context or the physical `with mesh:` context (Auto axis types)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
+    """The mesh visible at trace time, set by ``jax.set_mesh(mesh)``. (A bare
+    ``with mesh:`` only sets JAX's private physical-mesh context, so callers
+    that want the model's sharding annotations enter ``jax.set_mesh``.)"""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 @dataclasses.dataclass(frozen=True)

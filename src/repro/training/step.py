@@ -36,7 +36,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models import model as M
@@ -151,18 +150,18 @@ def _make_virtual_train_step(cfg, optimizer: Optimizer, n_virtual: int,
         # Full-manual shard_map over BOTH mesh axes: params replicate
         # (in_spec P()), every device computes its virtual workers at the
         # fixed (per, seq) shape, per-vw results come back stacked over the
-        # virtual axis. check_rep=False: the replicated-params claim is
+        # virtual axis. check_vma=False: the replicated-params claim is
         # ours, not inferrable. (Partial-auto over the model axis is not
         # supported by this XLA; deterministic mode therefore replicates
         # model-axis compute too — the documented cost of vw mode.)
         pspec = jax.tree.map(lambda _: P(), state["params"])
         bspec = {k: P("data") for k in batch}
         gspec = jax.tree.map(lambda _: P("data"), state["params"])
-        losses, xents, auxes, grads = shard_map(
+        losses, xents, auxes, grads = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P(), bspec),
             out_specs=(P("data"), P("data"), P("data"), gspec),
-            check_rep=False)(state["params"], state["step"], batch)
+            check_vma=False)(state["params"], state["step"], batch)
 
         # fixed virtual-order tree reduction: the ONLY cross-device sum,
         # and its order is a function of n_virtual alone

@@ -964,7 +964,7 @@ def test_parse_workload_synthesizes_live_specs():
 
 def test_compile_cache_option_configures_jax(tmp_path):
     import jax
-    from repro.cluster.executor import enable_compile_cache
+    from repro.launch.devices import enable_compile_cache
     old = {k: getattr(jax.config, k) for k in
            ("jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
@@ -977,6 +977,41 @@ def test_compile_cache_option_configures_jax(tmp_path):
     finally:
         for k, v in old.items():
             jax.config.update(k, v)
+
+
+CACHE_PROBE = """
+import os, sys, jax
+from repro.launch.devices import DEFAULT_CACHE_DIR, enable_compile_cache
+d = enable_compile_cache(sys.argv[1] if len(sys.argv) > 1 else None)
+jax.jit(lambda x: x * 3 + 1)(2.0).block_until_ready()
+print(d == str(DEFAULT_CACHE_DIR), d, len(os.listdir(d)))
+"""
+
+
+@pytest.mark.parametrize("source", ["env", "option", "default"])
+def test_compile_cache_directory_choice(tmp_path, source):
+    """JAX_COMPILATION_CACHE_DIR wins (and is left to JAX itself), then
+    the --compile-cache directory, then the fixed .jax_cache/ of the
+    checkout; the compile lands in the one chosen."""
+    env = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    argv = []
+    if source == "env":
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "env")
+        argv = [str(tmp_path / "option")]
+    elif source == "option":
+        argv = [str(tmp_path / "option")]
+    out = subprocess.run([sys.executable, "-c", CACHE_PROBE, *argv],
+                         capture_output=True, text=True, env=env, cwd=ROOT,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    is_default, where, n_entries = out.stdout.split()
+    assert int(n_entries) >= 1, "the compile was not cached there"
+    if source == "default":
+        assert is_default == "True"
+    else:
+        assert where == str(tmp_path / source)
+        assert not (tmp_path / "option").exists() or source == "option"
 
 
 # ------------------------------------ one policy interface, two substrates
@@ -1272,9 +1307,11 @@ def test_reshape_bench_beats_checkpoint_stop_resume():
 
 @pytest.mark.slow
 def test_live_cluster_tiresias_policy_transfers_devices():
+    # a outlives the background compile of its shrunk shape even when the
+    # persistent compile cache serves c's launch and the rounds run fast
     s = run_cluster_driver(
         "--policy", "elastic-tiresias",
-        "--jobs", "a=vgg19:2:20@0,b=resnet50:2:25@0,c=googlenet:2:12@6")
+        "--jobs", "a=vgg19:2:40@0,b=resnet50:2:25@0,c=googlenet:2:12@6")
     assert s["conserved"] is True
     assert s["finished"] == 3, s["jobs"]
     sin = [e for e in s["events"] if e["op"] == "scale_in"]

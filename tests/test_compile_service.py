@@ -40,6 +40,38 @@ def _blocked_service(workers=1):
     return svc, release, order
 
 
+# ------------------------------------------------- a prep that fails
+@pytest.mark.parametrize("engine", ["thread", "compile_service"])
+def test_failed_background_prep_raises_at_next_step(engine):
+    """A scaling operation whose background compile fails must not sit in
+    PREPARING for ever: the next step() raises, the operation is dropped,
+    and the job trains on at its old shape."""
+    from repro.configs import get_config
+    from repro.core import ElasticTrainer
+    from repro.core.elastic_runtime import PrepFailed
+    from repro.core.scaling import Phase
+    trainer = ElasticTrainer(get_config("edl-paper", smoke=True),
+                             global_batch=2, seq_len=16, init_parallelism=1,
+                             n_samples=64, d_partitions=4)
+    if engine == "compile_service":
+        trainer.compile_service = CompileService(workers=1)
+
+    def refuse(*a, **k):
+        raise RuntimeError("RESOURCE_EXHAUSTED: compile refused")
+    trainer._build_exec = refuse
+    trainer._exec_cache.clear()     # the shape is not warm: prep runs
+    trainer.migrate(1, block=False)
+    assert trainer.controller.phase is Phase.PREPARING
+    assert trainer.join_prep(60)
+    with pytest.raises(PrepFailed, match="RESOURCE_EXHAUSTED") as err:
+        trainer.step()
+    assert isinstance(err.value.__cause__, RuntimeError)
+    assert trainer.controller.phase is Phase.IDLE
+    assert trainer.step() is not None and trainer.step_idx == 1
+    if trainer.compile_service is not None:
+        trainer.compile_service.shutdown()
+
+
 # ------------------------------------------------------------- the queue
 def test_committed_outranks_speculative():
     svc, release, order = _blocked_service()

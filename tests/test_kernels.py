@@ -145,3 +145,33 @@ def test_wkv6_extreme_decay_stable():
         y, sT = wkv6(r, k, v, logw, u, s0, chunk=16)
         assert np.isfinite(np.asarray(y)).all()
         assert np.isfinite(np.asarray(sT)).all()
+
+
+# ------------------------------------------------- interpret mode choice
+class _Launched(Exception):
+    pass
+
+
+@pytest.mark.parametrize("kernel", ["flash", "wkv6"])
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False)])
+def test_wrappers_pick_interpret_mode_from_the_backend(
+        monkeypatch, kernel, backend, interpret):
+    """The model-layout wrappers interpret the kernel off the TPU and
+    compile it on the TPU — never interpret there."""
+    from jax.experimental import pallas as pl
+    seen = {}
+
+    def fake_pallas_call(*a, interpret, **k):
+        seen["interpret"] = interpret
+        raise _Launched
+    monkeypatch.setattr(pl, "pallas_call", fake_pallas_call)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(_Launched):
+        if kernel == "flash":
+            x = jnp.zeros((1, 1, 1, 128, 64))
+            flash_attention(x, x[:, :, 0], x[:, :, 0])
+        else:
+            x = jnp.zeros((1, 32, 1, 16))
+            wkv6(x, x, x, x, jnp.zeros((1, 16)), jnp.zeros((1, 1, 16, 16)))
+    assert seen["interpret"] is interpret
