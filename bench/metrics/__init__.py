@@ -1,0 +1,3 @@
+"""One reader per metric, found by the metric's name in BENCHMARK.json:
+``read(run)`` returns the number, or None where the run holds nothing to
+read (the harness then leaves the metric out of the line)."""
