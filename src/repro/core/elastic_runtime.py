@@ -33,6 +33,7 @@ from repro.data.pipeline import DynamicDataPipeline, VirtualWorkerPipeline
 from repro.data.synthetic import SyntheticTokenDataset
 from repro.data.worker import WorkerDataIterator
 from repro.launch.mesh import make_mesh
+from repro.obs.trace import span
 from repro.optim import Optimizer, adamw
 from repro.training.step import batch_sharding, init_train_state, \
     make_train_step, state_sharding
@@ -265,7 +266,7 @@ class ElasticTrainer:
         return (p, mp, tuple(d.id for d in devs[: p * mp]))
 
     def _build_exec(self, p: int, mp: int | None = None,
-                    devices=None) -> ExecHandle:
+                    devices=None, *, adj: int | None = None) -> ExecHandle:
         """Execution-context preparation for shape (p, mp): mesh +
         shardings + AOT-compiled step. This is the cost stop-free scaling
         hides. ``mp`` defaults to the job's current model-parallel degree;
@@ -283,29 +284,33 @@ class ElasticTrainer:
         the cache — a restarted process pays context preparation from
         zero. Cache access is lock-guarded: the compile service may build
         speculative handles on a worker thread while the main thread
-        steps; the expensive compile itself runs outside the lock."""
+        steps; the expensive compile itself runs outside the lock. ``adj``
+        is the admission number of the adjustment the build serves, where
+        there is one (the ``edl.adjust.prep`` span carries it)."""
         mp = mp if mp is not None else self.model_parallel
         devs = list(devices if devices is not None else self.devices)
         key = self._exec_key(p, mp, devs)
-        with self._exec_lock:
-            cached = self._exec_cache.get(key)
-            if cached is not None:
-                self._exec_cache[key] = self._exec_cache.pop(key)  # LRU
-                return cached
-        mesh = make_mesh(p, mp, devices=np.array(devs[: p * mp]))
-        step_fn, abstract_args, st_sh, b_sh = jit_step(
-            self.cfg, self.optimizer, mesh, seq_len=self.seq_len,
-            global_batch=self.global_batch, n_virtual=self.n_virtual,
-            seed=self.seed)
-        if self.use_aot:
-            with jax.set_mesh(mesh):
-                step_fn = step_fn.lower(*abstract_args).compile()
-        handle = ExecHandle(p, mp, mesh, step_fn, st_sh, b_sh)
-        with self._exec_lock:
-            handle = self._exec_cache.setdefault(key, handle)
-            while len(self._exec_cache) > EXEC_CACHE_MAX:
-                self._exec_cache.pop(next(iter(self._exec_cache)))
-        return handle
+        known = {} if adj is None else {"adj": adj}
+        with span("edl.adjust.prep", shape=f"{p}x{mp}", **known):
+            with self._exec_lock:
+                cached = self._exec_cache.get(key)
+                if cached is not None:
+                    self._exec_cache[key] = self._exec_cache.pop(key)  # LRU
+                    return cached
+            mesh = make_mesh(p, mp, devices=np.array(devs[: p * mp]))
+            step_fn, abstract_args, st_sh, b_sh = jit_step(
+                self.cfg, self.optimizer, mesh, seq_len=self.seq_len,
+                global_batch=self.global_batch, n_virtual=self.n_virtual,
+                seed=self.seed)
+            if self.use_aot:
+                with jax.set_mesh(mesh):
+                    step_fn = step_fn.lower(*abstract_args).compile()
+            handle = ExecHandle(p, mp, mesh, step_fn, st_sh, b_sh)
+            with self._exec_lock:
+                handle = self._exec_cache.setdefault(key, handle)
+                while len(self._exec_cache) > EXEC_CACHE_MAX:
+                    self._exec_cache.pop(next(iter(self._exec_cache)))
+            return handle
 
     # -------------------------------------------------------------- stepping
     def _assemble_batch(self) -> dict | None:
@@ -358,7 +363,18 @@ class ElasticTrainer:
     def step(self) -> dict | None:
         """One synchronous mini-batch across the current topology. Raises
         ``PrepFailed`` (and drops the operation) when the background context
-        prep of the scaling operation in flight failed."""
+        prep of the scaling operation in flight failed.
+
+        Profiler spans, each with ``step``: ``edl.step`` (the whole call),
+        and inside it ``.batch`` (assembly), ``.put`` (host-to-device),
+        ``.dispatch`` (the step program's call), ``.wait`` (until the loss
+        is ready) and ``.post`` (host bookkeeping and read-back, up to the
+        boundary's commit check)."""
+        with span("edl.step", step=self.step_idx, p=self.p,
+                  mp=self.model_parallel):
+            return self._step()
+
+    def _step(self) -> dict | None:
         if self._prep_error is not None:
             err, self._prep_error = self._prep_error, None
             op = self.controller.plan.record.op
@@ -366,17 +382,29 @@ class ElasticTrainer:
             raise PrepFailed(f"{self.job_handle}: background context prep "
                              f"for {op} failed: {err!r}") from err
         t0 = time.monotonic()
-        batch = self._assemble_batch()
+        n = self.step_idx
+        with span("edl.step.batch", step=n, rows=self.global_batch):
+            batch = self._assemble_batch()
         if batch is None:
             return None
-        dev_batch = jax.device_put(batch, self.exec.batch_shardings)
-        self.state, metrics = self.exec.step_fn(self.state, dev_batch)
+        with span("edl.step.put", step=n,
+                  bytes=sum(v.nbytes for v in batch.values())):
+            dev_batch = jax.device_put(batch, self.exec.batch_shardings)
+        with span("edl.step.dispatch", step=n):
+            self.state, metrics = self.exec.step_fn(self.state, dev_batch)
         # first chance: the switch is already due at this step's boundary
         # (the DRAINING mini-batch). JAX dispatch is async — step_fn's
         # outputs are futures — so the state move onto the new mesh can be
         # issued NOW and overlap the device compute itself.
         self._maybe_stage_switch()
-        jax.block_until_ready(metrics["loss"])
+        with span("edl.step.wait", step=n):
+            jax.block_until_ready(metrics["loss"])
+        with span("edl.step.post", step=n):
+            out = self._post_step(t0, metrics)
+        self.notify_batch_end()
+        return out
+
+    def _post_step(self, t0: float, metrics) -> dict:
         # second chance: the prep landed DURING this step (typical when
         # k = 1: the switch commits at the very boundary the handle
         # arrives before). Issued here, the transfers still overlap the
@@ -406,7 +434,6 @@ class ElasticTrainer:
         out = {k: float(v) for k, v in metrics.items()}
         out.update(step=self.step_idx, p=self.p, step_time=t_step)
         self.metrics_log.append(out)
-        self.notify_batch_end()
         return out
 
     # --------------------------------------------------- EDL control plane
@@ -524,82 +551,90 @@ class ElasticTrainer:
                  dead: tuple = ()):
         target_mp = (target_mp if target_mp is not None
                      else self.model_parallel)
-        avail = len(self.devices) // target_mp
-        if target_p > avail:
-            raise ValueError(f"need {target_p} slices of {target_mp} "
-                             f"device(s), have {avail}")
-        if self.global_batch % target_p:
-            raise ValueError(f"global batch {self.global_batch} not "
-                             f"divisible by p={target_p}")
-        if self.n_virtual and self.n_virtual % target_p:
-            raise ValueError(
-                f"p={target_p} must divide virtual_workers="
-                f"{self.n_virtual} (virtual blocks stay contiguous and "
-                f"equal-sized at every shape)")
-        plan = self.controller.admit(op, self.p, target_p)  # raises Busy
-        plan.record.from_mp = self.model_parallel
-        plan.record.to_mp = target_mp
-        plan.exiting = tuple(victims or ())
-        plan.dead_exiting = tuple(dead)
-        plan.joining = ("new",) * (n_join or max(0, target_p - self.p))
-        plan.release_devices = release
-        steps_before = self.step_idx
         key = self._exec_key(target_p, target_mp)
-        plan.record.exec_cache_key = key
         with self._exec_lock:
             cache_hit = key in self._exec_cache
-        plan.record.compile_cache_hit = cache_hit
+        # with block=True the span also holds the steps up to the commit
+        with span("edl.adjust.request", adj=self.controller.admitted, op=op,
+                  cache_hit=cache_hit,
+                  **{"from": f"{self.p}x{self.model_parallel}",
+                     "to": f"{target_p}x{target_mp}"}):
+            avail = len(self.devices) // target_mp
+            if target_p > avail:
+                raise ValueError(f"need {target_p} slices of {target_mp} "
+                                 f"device(s), have {avail}")
+            if self.global_batch % target_p:
+                raise ValueError(f"global batch {self.global_batch} not "
+                                 f"divisible by p={target_p}")
+            if self.n_virtual and self.n_virtual % target_p:
+                raise ValueError(
+                    f"p={target_p} must divide virtual_workers="
+                    f"{self.n_virtual} (virtual blocks stay contiguous and "
+                    f"equal-sized at every shape)")
+            plan = self.controller.admit(op, self.p, target_p)  # raises Busy
+            adj = plan.record.adj
+            plan.record.from_mp = self.model_parallel
+            plan.record.to_mp = target_mp
+            plan.exiting = tuple(victims or ())
+            plan.dead_exiting = tuple(dead)
+            plan.joining = ("new",) * (n_join or max(0, target_p - self.p))
+            plan.release_devices = release
+            steps_before = self.step_idx
+            plan.record.exec_cache_key = key
+            plan.record.compile_cache_hit = cache_hit
 
-        def finish(handle):
-            k = max(1, math.ceil(self.time_allowance_s /
-                                 max(self.step_time_ema or 0.01, 1e-4)))
-            plan.record.steps_during_prep = self.step_idx - steps_before
-            self.controller.prepared(self.step_idx + k, handle)
+            def finish(handle):
+                k = max(1, math.ceil(self.time_allowance_s /
+                                     max(self.step_time_ema or 0.01, 1e-4)))
+                plan.record.steps_during_prep = self.step_idx - steps_before
+                self.controller.prepared(self.step_idx + k, handle)
 
-        def prepare():
-            finish(self._build_exec(target_p, target_mp))
+            def build():
+                return self._build_exec(target_p, target_mp, adj=adj)
 
-        def prepare_in_background():
-            try:
+            def prepare():
+                finish(build())
+
+            def prepare_in_background():
+                try:
+                    prepare()
+                except Exception as e:      # raised by the next step()
+                    self._prep_error = e
+
+            if block:
                 prepare()
-            except Exception as e:      # raised by the next step()
-                self._prep_error = e
+                # commit at the next boundary manually
+                while self.controller.phase is Phase.SCHEDULED:
+                    if self.step() is None:
+                        self._commit_switch()
+                return self.controller.history[-1]
+            if cache_hit:
+                # warm shape (prefetched, or one this job already ran at):
+                # prep IS the cache lookup — schedule inline, no thread or
+                # ticket round trip, prep_s collapses to microseconds
+                prepare()
+                return None
+            svc = self.compile_service
+            if svc is not None:
+                from repro.core.compile_service import DONE, PRIO_COMMITTED
 
-        if block:
-            prepare()
-            # commit at the next boundary manually
-            while self.controller.phase is Phase.SCHEDULED:
-                if self.step() is None:
-                    self._commit_switch()
-            return self.controller.history[-1]
-        if cache_hit:
-            # warm shape (prefetched, or one this job already ran at):
-            # prep IS the cache lookup — schedule inline, no thread or
-            # ticket round trip, prep_s collapses to microseconds
-            prepare()
+                def on_ticket(t):
+                    if t.state != DONE:     # raised by the next step()
+                        self._prep_error = t.error or RuntimeError(
+                            f"context prep ticket {key} ended {t.state}")
+                        return
+                    finish(t.value)
+
+                # dedup/escalation: if a speculative prefetch of this shape
+                # is already pending or running, this JOINS it as committed
+                self._prep_ticket = svc.submit(
+                    key, build, priority=PRIO_COMMITTED, owner=self.job_handle)
+                self._prep_ticket.add_done_callback(on_ticket)
+                return None
+            self._prep_thread = threading.Thread(target=prepare_in_background,
+                                                 daemon=True)
+            self._prep_thread.start()
             return None
-        svc = self.compile_service
-        if svc is not None:
-            from repro.core.compile_service import DONE, PRIO_COMMITTED
-
-            def on_ticket(t):
-                if t.state != DONE:     # raised by the next step()
-                    self._prep_error = t.error or RuntimeError(
-                        f"context prep ticket {key} ended {t.state}")
-                    return
-                finish(t.value)
-
-            # dedup/escalation: if a speculative prefetch of this shape
-            # is already pending or running, this JOINS it as committed
-            self._prep_ticket = svc.submit(
-                key, lambda: self._build_exec(target_p, target_mp),
-                priority=PRIO_COMMITTED, owner=self.job_handle)
-            self._prep_ticket.add_done_callback(on_ticket)
-            return None
-        self._prep_thread = threading.Thread(target=prepare_in_background,
-                                             daemon=True)
-        self._prep_thread.start()
-        return None
 
     def _maybe_stage_switch(self):
         """Stage the state move when a ready switch commits at the current
@@ -620,28 +655,62 @@ class ElasticTrainer:
         move)."""
         if plan.staged_state is not None:
             return
-        plan.record.t_stage_start = self.controller.clock()
+        rec = plan.record
+        rec.t_stage_start = self.controller.clock()
         handle: ExecHandle = plan.exec_handle
-        if plan.record.op == "reshape":
-            from repro.reshape import StateSpec, apply_plan, plan_reshard
-            src = StateSpec.for_trainer(self)
-            dst = StateSpec.from_shardings(handle.p, handle.mp,
-                                           handle.state_shardings,
-                                           self.state)
-            rplan = plan_reshard(src, dst)
-            plan.record.reshard_bytes_moved = rplan.bytes_moved
-            plan.record.reshard_bytes_kept = rplan.bytes_kept
-            plan.record.bytes_moved_overlapped = rplan.bytes_moved
-            staged = apply_plan(rplan, self.state, handle.state_shardings)
-        else:
-            staged = jax.device_put(self.state, handle.state_shardings)
-        plan.staged_state = staged
+        rplan = self._reshard_plan(handle)
+        if rec.op == "reshape":
+            rec.reshard_bytes_moved = rplan.bytes_moved
+            rec.reshard_bytes_kept = rplan.bytes_kept
+            rec.bytes_moved_overlapped = rplan.bytes_moved
+        with span("edl.adjust.staged_reshard", adj=rec.adj, op=rec.op,
+                  bytes=rplan.bytes_moved):
+            plan.staged_state = self._move_state(rec.op, rplan, handle)
         plan.staged_from = self.state
-        plan.record.t_stage_end = self.controller.clock()
+        rec.t_stage_end = self.controller.clock()
+
+    def _reshard_plan(self, handle: ExecHandle):
+        """The planner's move from the live layout to ``handle``'s (its
+        ``bytes_moved`` prices a resize's move too)."""
+        from repro.reshape import StateSpec, plan_reshard
+        src = StateSpec.for_trainer(self)
+        dst = StateSpec.from_shardings(handle.p, handle.mp,
+                                       handle.state_shardings, self.state)
+        return plan_reshard(src, dst)
+
+    def _move_state(self, op: str, rplan, handle: ExecHandle):
+        """Start the state move onto ``handle``'s mesh: a reshape along the
+        planner's moves, plain data-axis scaling by a direct device_put."""
+        if op == "reshape":
+            from repro.reshape import apply_plan
+            return apply_plan(rplan, self.state, handle.state_shardings)
+        return jax.device_put(self.state, handle.state_shardings)
 
     def _commit_switch(self):
-        """The brief stop: reshard state (model broadcast) + swap topology."""
+        """The brief stop: reshard state (model broadcast) + swap topology.
+        It ends once the whole moved state is ready on the new mesh, so the
+        record's stop time is the device's move, not its enqueue."""
         plan = self.controller.plan
+        rec = plan.record
+        staged = (plan.staged_state is not None
+                  and plan.staged_from is self.state)
+        with span("edl.adjust.stop_window", adj=rec.adj, op=rec.op,
+                  staged=staged):
+            rec, freed = self._switch(plan, staged)
+        if freed and self.on_devices_released is not None:
+            # let the hook know WHICH verb is freeing (a reshape's surplus
+            # is not a data-parallel scale-in; event logs must not invent
+            # a p-transition that never happened)
+            self._releasing_op = rec.op
+            try:
+                self.on_devices_released(self, freed)
+            finally:
+                self._releasing_op = None
+        return rec
+
+    def _switch(self, plan, staged: bool):
+        """The stop window itself; returns the completed record and the
+        devices it frees."""
         self.controller.begin_switch()
         handle: ExecHandle = plan.exec_handle
         op = plan.record.op
@@ -669,23 +738,19 @@ class ElasticTrainer:
         # A reshape routes through the planner so the record carries the
         # move accounting; plain data-axis scaling keeps the direct
         # device_put.
-        if plan.staged_state is not None and plan.staged_from is self.state:
+        adj = plan.record.adj
+        if staged:
             self.state = plan.staged_state
-        elif op == "reshape":
-            from repro.reshape import StateSpec, apply_plan, plan_reshard
-            src = StateSpec.for_trainer(self)
-            dst = StateSpec.from_shardings(handle.p, handle.mp,
-                                           handle.state_shardings,
-                                           self.state)
-            rplan = plan_reshard(src, dst)
-            plan.record.reshard_bytes_moved = rplan.bytes_moved
-            plan.record.reshard_bytes_kept = rplan.bytes_kept
-            plan.record.bytes_moved_overlapped = 0
-            self.state = apply_plan(rplan, self.state,
-                                    handle.state_shardings)
         else:
-            self.state = jax.device_put(self.state, handle.state_shardings)
-        jax.block_until_ready(jax.tree.leaves(self.state)[0])
+            rplan = self._reshard_plan(handle)
+            if op == "reshape":
+                plan.record.reshard_bytes_moved = rplan.bytes_moved
+                plan.record.reshard_bytes_kept = rplan.bytes_kept
+                plan.record.bytes_moved_overlapped = 0
+            with span("edl.adjust.move", adj=adj, bytes=rplan.bytes_moved):
+                self.state = self._move_state(op, rplan, handle)
+        with span("edl.adjust.ready", adj=adj):
+            jax.block_until_ready(self.state)
         self.exec = handle
         self.p = handle.p
         self.model_parallel = handle.mp
@@ -695,17 +760,7 @@ class ElasticTrainer:
             # (cluster executor reclaim): the job stops owning those devices
             in_use = handle.p * handle.mp
             freed, self.devices = self.devices[in_use:], self.devices[:in_use]
-        rec = self.controller.complete()
-        if freed and self.on_devices_released is not None:
-            # let the hook know WHICH verb is freeing (a reshape's surplus
-            # is not a data-parallel scale-in; event logs must not invent
-            # a p-transition that never happened)
-            self._releasing_op = rec.op
-            try:
-                self.on_devices_released(self, freed)
-            finally:
-                self._releasing_op = None
-        return rec
+        return self.controller.complete(), freed
 
     # ------------------------------------------------ device pool hand-off
     def grant_devices(self, new_devices, *, block: bool = False

@@ -62,6 +62,9 @@ class ScalingRecord:
     # switch took the in-stop move instead
     t_stage_start: float = 0.0
     t_stage_end: float = 0.0
+    # the controller's admission number: every edl.adjust.* profiler span
+    # of this operation carries it as ``adj``
+    adj: int = -1
 
     @property
     def prep_time(self) -> float:
@@ -129,6 +132,7 @@ class ScalingController:
         self.phase = Phase.IDLE
         self.plan: SwitchPlan | None = None
         self.history: list[ScalingRecord] = []
+        self.admitted = 0       # the number the next admitted record takes
         # observability hooks fired with the finished record at complete()
         # — AFTER the controller is back to IDLE, so a listener that
         # inspects (or even requests) scaling sees a consistent machine
@@ -137,7 +141,9 @@ class ScalingController:
     def admit(self, op: str, from_p: int, to_p: int) -> SwitchPlan:
         if self.phase is not Phase.IDLE:
             raise Busy(f"scaling {self.plan.record.op} in flight")
-        rec = ScalingRecord(op, from_p, to_p, t_request=self.clock())
+        rec = ScalingRecord(op, from_p, to_p, t_request=self.clock(),
+                            adj=self.admitted)
+        self.admitted += 1
         self.plan = SwitchPlan(to_p, rec)
         self.phase = Phase.PREPARING
         rec.t_prep_start = self.clock()

@@ -79,18 +79,23 @@ def block_forward(cfg, p, x, *, mixer: str, ffn: str, positions, cache=None,
     """Returns (x, new_cache, aux_loss)."""
     cd = dt(cfg, "compute")
     h = apply_rmsnorm(p["norm1"], x, cfg.norm_eps)
-    mix_out, new_cache = MIXERS[mixer][1](
-        cfg, p["mixer"], h, positions=positions, cache=cache,
-        use_pallas=use_pallas)
+    # named scopes name the ops (forward, recompute and backward) in the
+    # compiled program's metadata and the profiler's op names; they change
+    # no instruction
+    with jax.named_scope("attention"):
+        mix_out, new_cache = MIXERS[mixer][1](
+            cfg, p["mixer"], h, positions=positions, cache=cache,
+            use_pallas=use_pallas)
     x = x + mix_out
     h = apply_rmsnorm(p["norm2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if ffn == "mlp":
-        f = apply_mlp(p["ffn"], h, cd)
-    elif ffn == "moe":
-        f, aux = moe_mod.moe_forward(cfg, p["ffn"], h)
-    else:   # rwkv channel-mix (keeps its own shift state)
-        f, cm_cache = ssm.rwkv_cm_forward(cfg, p["ffn"], h, cache=cache)
-        if cm_cache is not None:
-            new_cache = {**(new_cache or {}), **cm_cache}
+    with jax.named_scope("mlp"):
+        if ffn == "mlp":
+            f = apply_mlp(p["ffn"], h, cd)
+        elif ffn == "moe":
+            f, aux = moe_mod.moe_forward(cfg, p["ffn"], h)
+        else:   # rwkv channel-mix (keeps its own shift state)
+            f, cm_cache = ssm.rwkv_cm_forward(cfg, p["ffn"], h, cache=cache)
+            if cm_cache is not None:
+                new_cache = {**(new_cache or {}), **cm_cache}
     return x + f, new_cache, aux

@@ -186,7 +186,8 @@ def forward(cfg, params, batch, *, mode: str, cache=None, use_pallas=False,
     x, new_cache_layers, aux = _stack_forward(
         cfg, params, x, positions=positions, cache=cache,
         use_pallas=use_pallas, mode=mode)
-    x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with jax.named_scope("head_loss"):
+        x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
     if mode == "train":
         return x, aux
@@ -230,7 +231,8 @@ def loss_fn(cfg, params, batch, *, use_pallas=False, rng=None):
     hidden, aux = forward(cfg, params, batch, mode="train",
                           use_pallas=use_pallas, rng=rng)
     labels = batch["labels"]
-    loss = chunked_xent(cfg, params, hidden, labels)
+    with jax.named_scope("head_loss"):
+        loss = chunked_xent(cfg, params, hidden, labels)
     aux_w = cfg.moe.aux_loss_weight if cfg.moe else 0.0
     return loss + aux_w * aux, {"xent": loss, "aux": aux}
 

@@ -19,6 +19,11 @@ Checkpoint saves, fault recoveries and serving reclaims get flat spans
 on the same timeline. ``chrome_trace()`` exports the Trace Event JSON
 that chrome://tracing and Perfetto load directly — "X" complete events
 in microseconds, one track (tid) per job.
+
+``span()`` is the other view: a profiler annotation around the work
+itself (``edl.step.*``, ``edl.adjust.*`` in the trainer), recorded only
+while ``jax.profiler`` traces, on the clock the device ops are stamped
+with. Its adjustment spans reuse the phase words above.
 """
 from __future__ import annotations
 
@@ -26,6 +31,16 @@ import contextlib
 import json
 import threading
 import time
+
+
+def span(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` whose keyword
+    ``args`` become the event's stats (keep values to numbers, bools and
+    strings without commas). While no profile records it costs one TraceMe
+    check; jax is imported on first use, so ``repro.obs`` imports without
+    it."""
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class Tracer:

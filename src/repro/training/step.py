@@ -49,6 +49,11 @@ def init_train_state(cfg, optimizer: Optimizer, key) -> dict:
             "step": jnp.zeros((), jnp.int32)}
 
 
+def _global_norm(grads):
+    return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree.leaves(grads)))
+
+
 def _vw_tree_reduce(x):
     """Fixed balanced binary-tree sum over the leading (virtual-worker)
     axis. The pairing order is a pure function of ``x.shape[0]`` —
@@ -87,10 +92,10 @@ def make_train_step(cfg, optimizer: Optimizer, use_pallas: bool = False, *,
             lambda g, a: constrain(g, a), grads, axes,
             is_leaf=lambda x: isinstance(x, tuple) and all(
                 isinstance(e, (str, type(None))) for e in x))
-        new_params, new_opt = optimizer.update(grads, state["opt"],
-                                               state["params"])
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"])
+            gnorm = _global_norm(grads)
         metrics = {"loss": loss, "xent": parts["xent"], "aux": parts["aux"],
                    "grad_norm": gnorm}
         return ({"params": new_params, "opt": new_opt,
@@ -171,12 +176,13 @@ def _make_virtual_train_step(cfg, optimizer: Optimizer, n_virtual: int,
         grads = jax.tree.map(lambda g: _vw_tree_reduce(g) / n_virtual, grads)
         grads = jax.tree.map(lambda g, a: constrain(g, a), grads, axes_tree,
                              is_leaf=is_axes)
-        new_params, new_opt = optimizer.update(grads, state["opt"],
-                                               state["params"])
-        # grad_norm is diagnostic-only: its leaf-internal reductions follow
-        # the sharded layout, so it is NOT part of the bitwise contract
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"])
+            # grad_norm is diagnostic-only: its leaf-internal reductions
+            # follow the sharded layout, so it is NOT part of the bitwise
+            # contract
+            gnorm = _global_norm(grads)
         metrics = {"loss": loss, "xent": xent, "aux": aux,
                    "grad_norm": gnorm}
         return ({"params": new_params, "opt": new_opt,
