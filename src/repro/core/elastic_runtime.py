@@ -35,6 +35,7 @@ from repro.data.worker import WorkerDataIterator
 from repro.launch.mesh import make_mesh
 from repro.obs.trace import span
 from repro.optim import Optimizer, adamw
+from repro.reshape import StateMove, StateSpec, apply_plan, plan_reshard
 from repro.training.step import batch_sharding, init_train_state, \
     make_train_step, state_sharding
 
@@ -58,6 +59,11 @@ class ExecHandle:
     step_fn: Callable
     state_shardings: object
     batch_shardings: object
+
+    @property
+    def key(self) -> tuple:
+        """The exec-cache key this handle was built under."""
+        return (self.p, self.mp, tuple(d.id for d in self.mesh.devices.flat))
 
 
 class ElasticTrainer:
@@ -175,6 +181,9 @@ class ElasticTrainer:
 
         # bring up the initial topology (this is job launch, not scaling)
         self._exec_cache: dict[tuple, ExecHandle] = {}
+        # compiled state moves, keyed on the (source, destination) pair of
+        # exec keys; built in an adjustment's prep beside the step
+        self._move_cache: dict[tuple, StateMove] = {}
         self._exec_lock = threading.Lock()
         self.p = init_parallelism
         self._worker_seq = 0
@@ -311,6 +320,29 @@ class ElasticTrainer:
                 while len(self._exec_cache) > EXEC_CACHE_MAX:
                     self._exec_cache.pop(next(iter(self._exec_cache)))
             return handle
+
+    def _state_move(self, src: ExecHandle, dst: ExecHandle, *,
+                    adj: int | None = None) -> StateMove:
+        """The compiled move of the state from ``src``'s layout onto
+        ``dst``'s, built once per pair (LRU-bounded like the exec cache)
+        in the adjustment's prep, so that no move compiles in a stop
+        window."""
+        key = (src.key, dst.key)
+        with self._exec_lock:
+            cached = self._move_cache.get(key)
+            if cached is not None:
+                self._move_cache[key] = self._move_cache.pop(key)  # LRU
+                return cached
+        known = {} if adj is None else {"adj": adj}
+        with span("edl.adjust.prep_move", **known,
+                  **{"from": f"{src.p}x{src.mp}", "to": f"{dst.p}x{dst.mp}"}):
+            move = StateMove(src.state_shardings, dst.state_shardings,
+                             _abstract_state(self.cfg, self.optimizer))
+        with self._exec_lock:
+            move = self._move_cache.setdefault(key, move)
+            while len(self._move_cache) > EXEC_CACHE_MAX:
+                self._move_cache.pop(next(iter(self._move_cache)))
+        return move
 
     # -------------------------------------------------------------- stepping
     def _assemble_batch(self) -> dict | None:
@@ -589,8 +621,12 @@ class ElasticTrainer:
                 plan.record.steps_during_prep = self.step_idx - steps_before
                 self.controller.prepared(self.step_idx + k, handle)
 
+            src = self.exec
+
             def build():
-                return self._build_exec(target_p, target_mp, adj=adj)
+                handle = self._build_exec(target_p, target_mp, adj=adj)
+                self._state_move(src, handle, adj=adj)
+                return handle
 
             def prepare():
                 finish(build())
@@ -645,7 +681,7 @@ class ElasticTrainer:
             self._stage_switch(plan)
 
     def _stage_switch(self, plan):
-        """Overlapped state move: issue the switch's reshard/device_put
+        """Overlapped state move: issue the switch's ``StateMove``
         against the CURRENT state (whose producing step may still be in
         flight — async dispatch queues the transfers behind it) into
         fresh destination buffers on the new mesh. The staged arrays are
@@ -663,28 +699,30 @@ class ElasticTrainer:
             rec.reshard_bytes_moved = rplan.bytes_moved
             rec.reshard_bytes_kept = rplan.bytes_kept
             rec.bytes_moved_overlapped = rplan.bytes_moved
+        move = self._state_move(self.exec, handle)
         with span("edl.adjust.staged_reshard", adj=rec.adj, op=rec.op,
-                  bytes=rplan.bytes_moved):
-            plan.staged_state = self._move_state(rec.op, rplan, handle)
+                  bytes=rplan.bytes_moved, host_bytes=move.host_bytes):
+            plan.staged_state = self._move_state(rplan, move, handle)
         plan.staged_from = self.state
         rec.t_stage_end = self.controller.clock()
 
     def _reshard_plan(self, handle: ExecHandle):
         """The planner's move from the live layout to ``handle``'s (its
         ``bytes_moved`` prices a resize's move too)."""
-        from repro.reshape import StateSpec, plan_reshard
         src = StateSpec.for_trainer(self)
         dst = StateSpec.from_shardings(handle.p, handle.mp,
                                        handle.state_shardings, self.state)
         return plan_reshard(src, dst)
 
-    def _move_state(self, op: str, rplan, handle: ExecHandle):
-        """Start the state move onto ``handle``'s mesh: a reshape along the
-        planner's moves, plain data-axis scaling by a direct device_put."""
-        if op == "reshape":
-            from repro.reshape import apply_plan
-            return apply_plan(rplan, self.state, handle.state_shardings)
-        return jax.device_put(self.state, handle.state_shardings)
+    def _move_state(self, rplan, move: StateMove, handle: ExecHandle):
+        """Start the state move onto ``handle``'s mesh along ``move``: every
+        switch (resize, grant, release, migrate, reshape) is a reshard, a
+        resize one that keeps ``mp``. Records the bytes that went through
+        host memory."""
+        state, host_bytes = apply_plan(rplan, self.state,
+                                       handle.state_shardings, move)
+        self.controller.plan.record.host_bytes = host_bytes
+        return state
 
     def _commit_switch(self):
         """The brief stop: reshard state (model broadcast) + swap topology.
@@ -735,9 +773,7 @@ class ElasticTrainer:
         # mini-batch staged the move (see _stage_switch) against exactly
         # this state, the transfers have been in flight since dispatch —
         # only the readiness wait + pointer swap remain in the stop.
-        # A reshape routes through the planner so the record carries the
-        # move accounting; plain data-axis scaling keeps the direct
-        # device_put.
+        # A reshape's record carries the planner's move accounting.
         adj = plan.record.adj
         if staged:
             self.state = plan.staged_state
@@ -747,8 +783,10 @@ class ElasticTrainer:
                 plan.record.reshard_bytes_moved = rplan.bytes_moved
                 plan.record.reshard_bytes_kept = rplan.bytes_kept
                 plan.record.bytes_moved_overlapped = 0
-            with span("edl.adjust.move", adj=adj, bytes=rplan.bytes_moved):
-                self.state = self._move_state(op, rplan, handle)
+            move = self._state_move(self.exec, handle)
+            with span("edl.adjust.move", adj=adj, bytes=rplan.bytes_moved,
+                      host_bytes=move.host_bytes):
+                self.state = self._move_state(rplan, move, handle)
         with span("edl.adjust.ready", adj=adj):
             jax.block_until_ready(self.state)
         self.exec = handle

@@ -57,6 +57,9 @@ class ScalingRecord:
     # (overlapped with the draining mini-batch); 0 = the whole state move
     # ran inside the stop
     bytes_moved_overlapped: int = 0
+    # bytes of the state move that went through host memory: 0 when the
+    # move stayed on the devices (reshape.StateMove's device routes)
+    host_bytes: int = 0
     # staged-reshard window (overlapped state move issued by the draining
     # mini-batch, see elastic_runtime._stage_switch); both 0.0 when the
     # switch took the in-stop move instead
@@ -85,7 +88,8 @@ class ScalingRecord:
                "e2e_s": round(self.e2e_time, 4),
                "steps_during_prep": self.steps_during_prep,
                "switch_step": self.switch_step,
-               "cache_hit": self.compile_cache_hit}
+               "cache_hit": self.compile_cache_hit,
+               "host_bytes": self.host_bytes}
         if self.exec_cache_key is not None:
             # JSON-safe: (p, mp, (device ids...)) -> flat list
             p, mp, devs = self.exec_cache_key

@@ -65,6 +65,7 @@ def teardown_trainer(trainer) -> list:
     trainer.state = None
     trainer.exec = None
     trainer._exec_cache.clear()
+    trainer._move_cache.clear()
     return devices
 
 
@@ -107,8 +108,8 @@ def resume_from_checkpoint(trainer, checkpoint_dir: str) -> dict:
                                        restored)
         rplan = plan_reshard(src, dst)      # raises on collection mismatch
         meta["reshard"] = rplan.summary()
-        trainer.state = apply_plan(rplan, restored,
-                                   trainer.exec.state_shardings)
+        trainer.state, _ = apply_plan(rplan, restored,
+                                      trainer.exec.state_shardings)
     else:   # pre-reshape checkpoint: layout-blind restore
         trainer.state = jax.device_put(restored,
                                        trainer.exec.state_shardings)
@@ -170,6 +171,7 @@ def stop_resume_rescale(trainer, target_p: int,
     trainer.state = None
     trainer.exec = None
     trainer._exec_cache.clear()
+    trainer._move_cache.clear()
     jax.clear_caches()
 
     # 3. rebuild execution context at the new shape (foreground!)

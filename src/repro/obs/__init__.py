@@ -88,6 +88,10 @@ class Observability:
         self._m_stop = m.histogram(
             "edl_stop_window_ms",
             "committed switches' stop window (training paused)")
+        self._m_host_bytes = m.counter(
+            "edl_reshard_host_bytes_total",
+            "bytes of committed switches' state moves that went through "
+            "host memory (0 while every move stays on the devices)")
         self._m_prep = m.histogram(
             "edl_prep_ms", "committed switches' background context prep")
         self._m_e2e = m.histogram(
@@ -134,6 +138,7 @@ class Observability:
         self.tracer.record_adjustment(name, rec)
         self._m_prep.observe(rec.prep_time * 1e3)
         self._m_stop.observe(rec.stop_time * 1e3)
+        self._m_host_bytes.inc(rec.host_bytes)
         self._m_e2e.observe(rec.e2e_time * 1e3)
         self.emit(KIND_ADJUST, rec.op, round=getattr(ex, "round", None),
                   job=name, jid=job.jid, **rec.summary())

@@ -15,6 +15,7 @@ Canonical names (see docs/observability.md for the full table):
   edl_events_total{op=...}        every legacy/bus event, by op
   edl_queue_wait_rounds           admission wait (arrival -> first grant)
   edl_stop_window_ms / edl_prep_ms / edl_adjust_e2e_ms   per switch
+  edl_reshard_host_bytes_total    state-move bytes through host memory
   edl_slo_attainment              serving tier, when present
 """
 from __future__ import annotations

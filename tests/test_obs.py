@@ -251,6 +251,25 @@ def test_prometheus_exposition_parses(obs_run):
     assert rounds == stats["rounds"]
 
 
+def test_reshard_host_bytes_counter_sums_the_committed_moves():
+    """``edl_reshard_host_bytes_total`` adds each committed switch's
+    ``host_bytes``: 0 for a move that stayed on the devices."""
+    from types import SimpleNamespace
+    from repro.core.scaling import ScalingRecord
+    obs = Observability()
+    job = SimpleNamespace(spec=SimpleNamespace(name="a"), jid=0)
+    for host_bytes in (0, 4096, 0):
+        rec = ScalingRecord("scale_in", 4, 2, host_bytes=host_bytes)
+        obs.on_adjustment(SimpleNamespace(round=1), job, rec)
+    types, samples = _parse_exposition(obs.metrics.exposition())
+    assert types["edl_reshard_host_bytes_total"] == "counter"
+    assert [v for name, _, v in samples
+            if name == "edl_reshard_host_bytes_total"] == [4096]
+    adjust = [ev for ev in obs.events() if ev.kind == "adjust"]
+    assert [ev.data["host_bytes"] for ev in adjust] == [0, 4096, 0]
+    obs.close()
+
+
 def test_prom_http_endpoint_serves_exposition():
     obs = Observability(prom_port=0)     # ephemeral loopback port
     try:

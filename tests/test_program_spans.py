@@ -137,6 +137,7 @@ def test_moves_carry_the_planners_bytes(traced):
                               (2, "edl.adjust.move", 0)]:
         (move,) = by_adj[adj][name]
         assert move.args["bytes"] == got["moved"][adj] > 0
+        assert move.args["host_bytes"] == 0    # the move stays on devices
         (stop,) = by_adj[adj]["edl.adjust.stop_window"]
         assert stop.args["staged"] == staged
         assert scopes.move_ms(by_adj[adj]) > 0

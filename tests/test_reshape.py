@@ -215,3 +215,146 @@ print(json.dumps({"ref": ref, "got": got,
     assert res["reshard"]["to"] == [4, 1]
     np.testing.assert_allclose(res["got"], res["ref"], rtol=1e-4), \
         "cross-shape restore must not disturb the loss trajectory"
+
+
+# ------------------------------------------- the live move, device to device
+MOVES_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
+    " --xla_force_host_platform_device_count=4"
+import json
+import jax
+import numpy as np
+from jax._src import array as jax_array
+from repro.configs import get_config
+from repro.core import ElasticTrainer
+from repro.optim import adamw
+from repro.reshape import StateMove, StateSpec, apply_plan, plan_reshard
+
+fetches = [0]                       # calls of the host fetch, ArrayImpl._value
+_value = jax_array.ArrayImpl._value
+
+
+def counted(self):
+    fetches[0] += 1
+    return _value.fget(self)
+
+
+jax_array.ArrayImpl._value = property(counted)
+
+
+def equal(a, b):
+    return all(jax.tree.leaves(jax.tree.map(
+        lambda x, y: x.dtype == y.dtype and np.array_equal(x, y), a, b)))
+
+
+tr = ElasticTrainer(get_config("edl-paper", smoke=True), global_batch=8,
+                    seq_len=32, init_parallelism=4, optimizer=adamw(1e-3),
+                    n_samples=512, d_partitions=8, seed=0,
+                    devices=jax.devices(), time_allowance_s=0.0)
+freed = []
+tr.on_devices_released = lambda t, devs: freed.extend(devs)
+tr.step()
+out = {}
+move_state = tr._move_state
+
+
+def walk(name, request):
+    seen = []
+
+    def spy(rplan, move, handle):
+        before = jax.device_get(tr.state)
+        n0 = fetches[0]
+        moved = move_state(rplan, move, handle)
+        jax.block_until_ready(moved)
+        n = fetches[0] - n0
+        seen.append({
+            "route": move.route, "fetches": n,
+            "equal": equal(before, jax.device_get(moved)),
+            "shardings": all(jax.tree.leaves(jax.tree.map(
+                lambda x, s: x.sharding == s, moved,
+                handle.state_shardings))),
+            "host_bytes": tr.controller.plan.record.host_bytes})
+        return moved
+
+    tr._move_state = spy
+    request()
+    while tr.controller.plan is not None:
+        tr.step()
+    tr._move_state = move_state
+    rec = tr.controller.history[-1]
+    (got,) = seen
+    out[name] = dict(got, to=[tr.p, tr.model_parallel], op=rec.op,
+                     record_host_bytes=rec.host_bytes)
+    tr.step()
+
+
+walk("release", lambda: tr.release_devices(2))
+walk("grant", lambda: tr.grant_devices(list(freed)))
+walk("reshape_2x2", lambda: tr.reshape(2, 2))
+walk("reshape_4x1", lambda: tr.reshape(4, 1))
+walk("migrate", lambda: tr.migrate(1, block=False))
+
+# the instrument sees the host path: plain device_put onto a release's layout
+target = tr._build_exec(2, 1)
+n0 = fetches[0]
+jax.block_until_ready(jax.device_put(tr.state, target.state_shardings))
+out["plain_device_put"] = {"fetches": fetches[0] - n0}
+
+# host (numpy) input, as a checkpoint restore gives: the counted fallback
+host = jax.device_get(tr.state)
+spec = StateSpec.for_trainer(tr)
+move = StateMove.between(host, tr.exec.state_shardings)
+moved, host_bytes = apply_plan(plan_reshard(spec, spec), host,
+                               tr.exec.state_shardings, move)
+out["host_input"] = {
+    "route": move.route, "host_bytes": host_bytes,
+    "state_bytes": sum(x.nbytes for x in jax.tree.leaves(host)),
+    "equal": equal(host, jax.device_get(moved)),
+    "shardings": all(jax.tree.leaves(jax.tree.map(
+        lambda x, s: x.sharding == s, moved, tr.exec.state_shardings)))}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def live_moves():
+    env = {**os.environ, "PYTHONPATH": os.path.join(ROOT, "src")}
+    env.pop("XLA_FLAGS", None)
+    res = subprocess.run([sys.executable, "-c", MOVES_SCRIPT],
+                         capture_output=True, text=True, env=env, cwd=ROOT,
+                         timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("adjustment,op,to,route", [
+    ("release", "scale_in", [2, 1], "shrink"),
+    ("grant", "scale_out", [4, 1], "grow"),
+    ("reshape_2x2", "reshape", [2, 2], "reshard"),
+    ("reshape_4x1", "reshape", [4, 1], "reshard"),
+    ("migrate", "migrate", [4, 1], "keep"),
+])
+def test_every_switch_moves_the_state_device_to_device(live_moves, adjustment,
+                                                       op, to, route):
+    """Each switch's move is bitwise the state it read, lands on the new
+    handle's shardings, and never fetches an array to the host."""
+    got = live_moves[adjustment]
+    assert (got["op"], got["to"], got["route"]) == (op, to, route)
+    assert got["equal"] and got["shardings"]
+    assert got["fetches"] == 0
+    assert got["host_bytes"] == got["record_host_bytes"] == 0
+
+
+def test_the_fetch_counter_sees_the_host_path(live_moves):
+    """The instrument above is live: a plain ``device_put`` onto a release's
+    layout fetches leaves to the host."""
+    assert live_moves["plain_device_put"]["fetches"] > 0
+
+
+@pytest.mark.parametrize("case", ["host_input"])
+def test_host_input_takes_the_counted_fallback(live_moves, case):
+    got = live_moves[case]
+    assert got["route"] == "host"
+    assert got["equal"] and got["shardings"]
+    assert got["host_bytes"] == got["state_bytes"] > 0
