@@ -19,7 +19,10 @@ kind, with one interface (``Driver`` there):
            work begun that the window sees through before it closes;
   .annotate
            set while the profiler records: wrap work in ``bench.*`` spans;
-  .free()  drop every device buffer and program the driver holds;
+  .free()  drop every device buffer and program the driver holds; with
+           ``--trace 1`` first put the HLO text (``as_text()``) of each
+           step program into ``run.hlo_texts``, where the named scopes
+           are looked up;
   .check() -> {"correct": bool, "numbers": {name: {value, limit}}}
            after ``free``: the comparison with the plain reference.
 
@@ -31,6 +34,13 @@ knows is refused.
 One run, in order: set-up (the driver's build and ``warm``), the window of
 ``--seconds`` (closing at the first step boundary with nothing in flight),
 ``free``, the reduction of the trace, and ``check``.
+
+With ``--trace 1`` the reduction fills ``run.reduced`` (device ops and the
+benchmark's spans), ``run.spans`` (the program's ``edl.*`` host spans with
+their args) and ``run.scopes`` (device seconds of each named scope over
+the traced steps, exclusive and averaged over the chips, with
+``unscoped``, ``containers`` and ``busy``, and apart each scope that the
+configuration names: ``bench.scopes.scope_times``).
 """
 from __future__ import annotations
 
@@ -93,6 +103,9 @@ class Run:
     peak_flops: float = 0.0
     reduced: object = None          # bench.trace.Reduced (--trace 1)
     traced: tuple = (0.0, 0.0)      # host seconds covered by the trace
+    hlo_texts: list = dataclasses.field(default_factory=list)
+    spans: list = dataclasses.field(default_factory=list)  # scopes.Span
+    scopes: dict = dataclasses.field(default_factory=dict)  # name -> s
 
 
 # ------------------------------------------------------------------ specs
@@ -142,8 +155,12 @@ def mix_with_defaults(traffic: dict, keys: dict) -> dict:
 
 def enable_compile_cache() -> str:
     """JAX's persistent cache: ``JAX_COMPILATION_CACHE_DIR`` when set, else
-    the fixed ``.jax_cache/`` of this checkout."""
+    the fixed ``.jax_cache/`` of this checkout. Its key holds the programs'
+    metadata: an executable that another commit cached would otherwise come
+    back with that commit's op names, and the named scopes with them."""
+    import jax
     from repro.launch.devices import enable_compile_cache as enable
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return enable(str(ROOT / ".jax_cache"))
 
 
@@ -214,10 +231,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     warm and freed, it is called with the driver in place of the check, and
     the run returns None."""
     import jax
-    from bench import peaks
+    from bench import peaks, scopes
 
     bench = bench if bench is not None else load_benchmark(root)
     cell, config, traffic = parts or cell_parts(bench, workload, root)
+    scopes.extra_scopes(config)         # a scope that is no name fails here
     devices = jax.devices()
     if require_tpu and devices[0].platform != "tpu":
         raise NoChip(f"JAX finds no TPU (platform {devices[0].platform})")
@@ -293,13 +311,27 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     # ---- free the program, reduce the trace, then the reference
     free(driver)
     if tracer is not None:
-        from bench import trace as trace_mod
-        traced = [st.t0 for st in steps
-                  if run.traced[0] <= st.t0 <= run.traced[1]]
-        run.reduced = trace_mod.align(tracer.reduce(), traced)
-        tracer.cleanup()
+        reduce_trace(run, tracer)
     checks = driver.check()
     return assemble(run, bench, checks, peak_bytes, devices)
+
+
+def reduce_trace(run: Run, tracer):
+    """Fill ``run.reduced``, ``run.spans`` and ``run.scopes`` from the
+    trace, then remove it."""
+    from bench import scopes, trace
+    traced = [st.t0 for st in run.steps
+              if run.traced[0] <= st.t0 <= run.traced[1]]
+    red = trace.align(tracer.reduce(), traced)
+    tracer.cleanup()
+    run.reduced = red
+    run.spans = red.program.spans
+    lo, hi = red.host_to_trace(run.traced)
+    run.scopes = scopes.scope_times(red.program, run.hlo_texts, lo, hi,
+                                    scopes.extra_scopes(run.config))
+    log("scopes", steps=len(traced), hlo_texts=len(run.hlo_texts),
+        **run.scopes)
+    run.hlo_texts = []
 
 
 def free(driver):

@@ -57,6 +57,20 @@ def _fp8_bwd(spec, res, g):
 fp8.defvjp(_fp8_fwd, _fp8_bwd)
 EINSUMS = {"exact": exact, "fp8": fp8}
 
+# program attribute <- configuration key: the widths that the program's
+# configuration must have as the file states them
+WIDTHS = {"d_model": "hidden_size", "d_ff": "intermediate_size",
+          "n_heads": "num_attention_heads",
+          "n_kv_heads": "num_key_value_heads",
+          "resolved_head_dim": "head_dim",
+          "tie_embeddings": "tie_word_embeddings"}
+# program attribute <- configuration key: what the file sets in the
+# program's own published configuration (its cuts, dtypes and constants)
+APPLIED = {"n_layers": "num_hidden_layers", "vocab": "vocab_size",
+           "param_dtype": "param_dtype", "compute_dtype": "compute_dtype",
+           "norm_eps": "rms_norm_eps", "rope_theta": "rope_theta",
+           "remat": "remat"}
+
 
 def dims(cfg: dict) -> tuple:
     d, h = cfg["hidden_size"], cfg["num_attention_heads"]
@@ -82,6 +96,24 @@ def param_shapes(cfg: dict) -> dict:
         "final_norm/scale": (d,),
         "unembed/w": (d, v),
     }
+
+
+def matmul_params(cfg: dict) -> int:
+    """Parameters that take part in a matmul: attention projections,
+    SwiGLU MLP and the LM head; the embedding lookup is no matmul."""
+    d, h, kv, hd, f, v, n = dims(cfg)
+    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
+    return n * per_layer + d * v
+
+
+def flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Model FLOPs of one trained token, by ``bench.flops``'s rule: 6 for
+    every matmul parameter (2 forward, 4 backward), plus causal
+    self-attention, 6 x seq x n_heads x head_dim a layer (QK^T and PV at
+    2 x seq/2 x n_heads x head_dim each forward, times 3 for forward and
+    backward)."""
+    d, h, kv, hd, f, v, n = dims(cfg)
+    return 6.0 * matmul_params(cfg) + 6 * n * seq_len * h * hd
 
 
 def _rmsnorm(x, g, eps):

@@ -1,10 +1,16 @@
 """The program's own names in a profiler trace (``.xplane.pb``): its host
 spans (``edl.*`` annotations, with their args) and the named scope of each
-device op (``attention``, ``mlp``, ``head_loss``, ``optimizer``), and the
-per-layer readings made from them.
+device op, and the per-layer readings made from them.
 
-Scopes come from the compiled modules' HLO text (``compiled.as_text()``),
-whose ``op_name`` metadata holds the scope path of each instruction, e.g.
+Scope names are ``SCOPES`` (the model's ``attention``, ``mlp``,
+``head_loss`` and the step's ``optimizer``), each op's innermost one; a
+configuration file may name more under ``"scopes"`` (``extra_scopes``),
+each read apart as the time of the ops whose path names it anywhere, so
+that an extra name takes nothing from ``SCOPES`` or from another; a
+metric's reader then reads ``run.scopes[<name>]``. Scopes come from the
+compiled modules' HLO text
+(``compiled.as_text()``), whose ``op_name`` metadata holds the scope path
+of each instruction, e.g.
 ``jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/mul``.
 The trace names an op by its instruction only (``%fusion.12 = ...``), and
 several modules may share a name (one step program per mesh shape, each
@@ -14,10 +20,11 @@ several modules may share a name (one step program per mesh shape, each
 its ops' instruction texts.
 
 Device ops nest: a ``while`` event contains its body's ops. Time is
-counted exclusively: an op with no op inside it (a leaf) gives its time to
-its scope, or to ``unscoped``; an op with ops inside it (a container)
-gives only the time none of them covers, to ``containers``. Per chip the
-three add up to the union of the ops' intervals, the chip's busy time.
+counted exclusively (``bench.trace.exclusive``): an op with no op inside
+it (a leaf) gives its time to its scope, or to ``unscoped``; an op with
+ops inside it (a container) gives only the time none of them covers, to
+``containers``. Per chip these add up to the union of the ops' intervals,
+the chip's busy time; an extra scope's leaf time lies inside it.
 """
 from __future__ import annotations
 
@@ -25,13 +32,13 @@ import bisect
 import dataclasses
 import re
 
-from bench.trace import _DEVICE, OPS_LINE, union
+from bench.trace import exclusive, union
 
 SCOPES = ("attention", "mlp", "head_loss", "optimizer")
-MODULES_LINE = "XLA Modules"
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _INSTR = re.compile(r"^\s*(?:ROOT )?(%\S+ = .*)$")
 _WRAPPED = re.compile(r"^[\w.-]+\((.*)\)$")
+_SCOPE_NAME = re.compile(r"^[A-Za-z_][A-Za-z0-9_]*$")
 
 
 @dataclasses.dataclass
@@ -53,40 +60,41 @@ class Profile:
 def read(path: str, prefix: str = "edl.") -> Profile:
     """The device ops (full instruction text), module runs and the host
     spans whose names start with ``prefix`` of one ``.xplane.pb``."""
-    from jax.profiler import ProfileData
-    ops, modules, spans = {}, {}, []
-    for plane in ProfileData.from_file(path).planes:
-        m = _DEVICE.match(plane.name)
-        if m:
-            chip = int(m.group(1))
-            for line in plane.lines:
-                if line.name in (OPS_LINE, MODULES_LINE):
-                    evs = sorted((e.start_ns, e.start_ns + e.duration_ns,
-                                  e.name) for e in line.events)
-                    (ops if line.name == OPS_LINE else modules)[chip] = evs
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith(prefix):
-                        spans.append(Span(e.name, e.start_ns,
-                                          e.start_ns + e.duration_ns,
-                                          dict(e.stats), line.name))
-    spans.sort(key=lambda s: (s.start, -s.end))
-    return Profile(ops, modules, spans)
+    from bench.trace import reduce_file
+    return reduce_file(path, prefix).program
+
+
+def extra_scopes(config: dict) -> tuple:
+    """The scopes that a configuration file names under ``"scopes"``
+    beyond ``SCOPES``, once each, in its order."""
+    extra = config.get("scopes", [])
+    if not isinstance(extra, list) or not all(
+            isinstance(n, str) and _SCOPE_NAME.match(n) for n in extra):
+        raise ValueError(f"configuration {config.get('name')!r} names "
+                         f"scopes that are no names: {extra!r}")
+    return tuple(n for n in dict.fromkeys(extra) if n not in SCOPES)
 
 
 # ------------------------------------------------------------ scopes
-def scope_of(path: str) -> str | None:
-    """The innermost of ``SCOPES`` in an ``op_name`` path, whose parts may
-    be wrapped by transformations (``jvp(head_loss)``,
+def path_parts(path: str) -> list:
+    """The parts of an ``op_name`` path, each unwrapped from the
+    transformations around it (``jvp(head_loss)``,
     ``transpose(jvp(attention))``)."""
-    found = None
+    out = []
     for part in path.split("/"):
         while True:
             m = _WRAPPED.match(part)
             if not m:
                 break
             part = m.group(1)
+        out.append(part)
+    return out
+
+
+def scope_of(path: str) -> str | None:
+    """The innermost of ``SCOPES`` in an ``op_name`` path."""
+    found = None
+    for part in path_parts(path):
         if part in SCOPES:
             found = part
     return found
@@ -108,30 +116,50 @@ def op_key(text: str) -> tuple:
     return name, rest, ""
 
 
-def instructions(hlo_text: str) -> dict:
-    """``op_key`` -> scope, for every instruction of one module's HLO
-    text."""
+def op_paths(hlo_text: str) -> dict:
+    """``op_key`` -> ``op_name`` path (None without one), for every
+    instruction of one module's HLO text."""
     out = {}
     for line in hlo_text.splitlines():
         m = _INSTR.match(line)
         if not m:
             continue
         meta = _OP_NAME.search(m.group(1))
-        out[op_key(m.group(1))] = scope_of(meta.group(1)) if meta else None
+        out[op_key(m.group(1))] = meta.group(1) if meta else None
     return out
+
+
+def instructions(hlo_text: str) -> dict:
+    """``op_key`` -> scope among ``SCOPES``, for every instruction of one
+    module's HLO text."""
+    return {k: scope_of(p) if p else None
+            for k, p in op_paths(hlo_text).items()}
 
 
 class Module:
     """One compiled module's instructions, looked up by a trace op's
-    text."""
+    text: ``table`` holds each one's scope among ``SCOPES``, ``extra``
+    the names of ``extra`` in its path (where there are any)."""
 
-    def __init__(self, hlo_text: str):
-        self.table = instructions(hlo_text)
+    def __init__(self, hlo_text: str, extra: tuple = ()):
+        paths = op_paths(hlo_text)
+        self.table = {k: scope_of(p) if p else None
+                      for k, p in paths.items()}
+        self.extra = {}
+        for k, p in paths.items():
+            parts = set(path_parts(p)) if p and extra else ()
+            named = tuple(n for n in extra if n in parts)
+            if named:
+                self.extra[k] = named
 
     def find(self, text: str):
         """(found, scope) of the op named ``text``."""
         key = op_key(text)
         return key in self.table, self.table.get(key)
+
+    def extras(self, text: str) -> tuple:
+        """The extra scopes in the path of the op named ``text``."""
+        return self.extra.get(op_key(text), ())
 
 
 def _run_at(runs: list, starts: list, t: float):
@@ -140,10 +168,10 @@ def _run_at(runs: list, starts: list, t: float):
     return runs[i][2] if i >= 0 and t < runs[i][1] else None
 
 
-def match_modules(prof: Profile, hlo_texts: list) -> dict:
+def match_modules(prof: Profile, hlo_texts: list, extra: tuple = ()) -> dict:
     """Module run name -> the ``Module`` of the HLO text that holds the
     most of the ops run under that name (None where no text holds any)."""
-    modules = [Module(t) for t in hlo_texts]
+    modules = [Module(t, extra) for t in hlo_texts]
     seen: dict = {}
     for chip, runs in prof.modules.items():
         starts = [r[0] for r in runs]
@@ -162,30 +190,16 @@ def match_modules(prof: Profile, hlo_texts: list) -> dict:
     return out
 
 
-def exclusive(ops: list) -> list:
-    """[(start, end, text, own_ns, is_leaf)]: each op's time not covered
-    by the ops nested in it."""
-    out, stack = [], []         # stack: indices into out of open ops
-    for s, e, text in sorted(ops, key=lambda o: (o[0], -(o[1] - o[0]))):
-        while stack and out[stack[-1]][1] <= s:
-            stack.pop()
-        if stack:
-            parent = out[stack[-1]]
-            parent[3] -= min(e, parent[1]) - s
-            parent[4] = False
-        out.append([s, e, text, e - s, True])
-        stack.append(len(out) - 1)
-    return [tuple(o) for o in out]
-
-
-def scope_times(prof: Profile, hlo_texts: list, lo: float,
-                hi: float) -> dict:
+def scope_times(prof: Profile, hlo_texts: list, lo: float, hi: float,
+                extra: tuple = ()) -> dict:
     """Device seconds of the ops within [lo, hi), averaged over the chips:
-    each scope's leaf ops, ``unscoped`` leaf ops (and those of a module run
-    that no HLO text matches), ``containers``' own time, and ``busy``, the
-    union of the ops, which the others add up to."""
-    modules = match_modules(prof, hlo_texts)
-    total = dict.fromkeys(SCOPES + ("unscoped", "containers", "busy"), 0.0)
+    the leaf ops of each of ``SCOPES``, ``unscoped`` leaf ops (and those
+    of a module run that no HLO text matches), ``containers``' own time,
+    and ``busy``, the union of the ops, which these add up to; and apart,
+    for each name of ``extra``, the leaf ops whose path names it."""
+    modules = match_modules(prof, hlo_texts, extra)
+    total = dict.fromkeys(SCOPES + ("unscoped", "containers", "busy")
+                          + extra, 0.0)
     for chip, ops in prof.ops.items():
         ops = [o for o in ops if o[0] >= lo and o[1] <= hi]
         runs = prof.modules.get(chip, [])
@@ -197,6 +211,8 @@ def scope_times(prof: Profile, hlo_texts: list, lo: float,
             mod = modules.get(_run_at(runs, starts, s))
             scope = mod.find(text)[1] if mod is not None else None
             total[scope or "unscoped"] += own
+            for name in mod.extras(text) if mod is not None else ():
+                total[name] += own
         total["busy"] += union(ops, lo, hi)
     n = max(len(prof.ops), 1)
     return {k: v / n / 1e9 for k, v in total.items()}
@@ -233,23 +249,33 @@ def adjustments(spans: list) -> dict:
     return out
 
 
-def move_ms(by_name: dict) -> float | None:
-    """One adjustment's move: from its start (that of ``staged_reshard``,
-    else of ``move``) to the end of ``ready``."""
+def move_ms(by_name: dict, waits: list = ()) -> float | None:
+    """One adjustment's move as far as it holds up training: from its
+    start (that of ``staged_reshard``, else of ``move``), or from the end
+    of the last ``edl.step.wait`` of ``waits`` before ``ready`` where that
+    is later, to the end of ``ready``. A staged move is issued behind the
+    draining step and queues behind it on the device; that step's wait
+    ends when the device has finished it, so the step's time is left
+    out."""
     start = by_name.get("edl.adjust.staged_reshard") or \
         by_name.get("edl.adjust.move")
     ready = by_name.get("edl.adjust.ready")
     if not start or not ready:
         return None
-    return (ready[-1].end - start[0].start) / 1e6
+    t0 = start[0].start
+    for w in waits:
+        if w.thread == ready[-1].thread and t0 < w.end <= ready[-1].start:
+            t0 = w.end
+    return (ready[-1].end - t0) / 1e6
 
 
 def adjust_move_ms(spans: list, lo: float, hi: float) -> float | None:
     """Mean ``move_ms`` over the adjustments whose spans all lie in
     [lo, hi)."""
+    waits = [s for s in spans if s.name == "edl.step.wait"]
     got = []
     for by_name in adjustments(spans).values():
-        ms = move_ms(by_name)
+        ms = move_ms(by_name, waits)
         first = min(s.start for v in by_name.values() for s in v)
         if ms is not None and first >= lo and \
                 max(s.end for v in by_name.values() for s in v) <= hi:

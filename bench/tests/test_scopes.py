@@ -73,7 +73,8 @@ def test_step_host_time_and_the_move_from_spans_made_by_hand():
     # step 0: 100 less the union of [5, 20) and [10, 70) = 35 ms;
     # step 1: 50 less [105, 148) = 7 ms
     assert scopes.step_host_ms(spans, 0, 200e6) == pytest.approx(21.0)
-    assert scopes.adjust_move_ms(spans, 0, 200e6) == pytest.approx(142.0)
+    # from the end of the last wait before ready, 140, to 147 ms
+    assert scopes.adjust_move_ms(spans, 0, 200e6) == pytest.approx(7.0)
     assert scopes.adjust_move_ms(spans, 10e6, 200e6) is None
     assert scopes.step_host_ms(spans, 200e6, 300e6) is None
 
@@ -130,12 +131,57 @@ def test_while_containers_are_not_counted_in_a_scope(recorded):
     assert got["mlp"] + got["attention"] < got["busy"]
 
 
-def test_scopes_and_the_remainder_add_up_to_the_busy_time(recorded):
+@pytest.mark.parametrize("config", [
+    {}, {"scopes": ["dot_general"]}, {"scopes": ["div", "mlp", "div"]}])
+@pytest.mark.parametrize("run", ["all", "first", "second"])
+def test_scopes_and_the_remainder_add_up_to_the_busy_time(recorded, config,
+                                                          run):
     prof, texts = recorded
     ops = prof.ops[0]
     lo, hi = ops[0][0], max(o[1] for o in ops)
-    got = scopes.scope_times(prof, texts, lo, hi)
-    parts = sum(got[k] for k in scopes.SCOPES) + got["unscoped"] + \
-        got["containers"]
+    if run != "all":                    # one module run of the six
+        lo, hi = prof.modules[0][0 if run == "first" else 1][:2]
+    extra = scopes.extra_scopes(config)
+    got = scopes.scope_times(prof, texts, lo, hi, extra)
+    assert set(got) == set(scopes.SCOPES + extra) | {
+        "unscoped", "containers", "busy"}
+    parts = sum(got[k] for k in scopes.SCOPES + ("unscoped", "containers"))
     assert got["busy"] == pytest.approx(trace.union(ops, lo, hi) / 1e9)
     assert parts == pytest.approx(got["busy"], rel=0.01)
+    for name in extra:
+        assert 0 < got[name] < got["busy"]
+
+
+def test_a_scope_named_in_a_configuration_is_read(recorded):
+    """The matmul's ``dot_general`` lies inside ``mlp`` in the first
+    program and inside ``attention`` in the second: named by a
+    configuration, it is read from their ops, and no other reading
+    moves."""
+    prof, texts = recorded
+    ops = prof.ops[0]
+    lo, hi = ops[0][0], max(o[1] for o in ops)
+    extra = scopes.extra_scopes({"scopes": ["mlp", "dot_general"]})
+    assert extra == ("dot_general",)
+    base = scopes.scope_times(prof, texts, lo, hi)
+    got = scopes.scope_times(prof, texts, lo, hi, extra)
+    assert 0.5 * (base["mlp"] + base["attention"]) < got["dot_general"] \
+        <= base["mlp"] + base["attention"]
+    assert {k: got[k] for k in base} == base
+
+
+def test_an_added_scope_moves_no_other_reading(recorded):
+    """``div`` and ``dot_general`` both lie in ``mlp``; naming one beside
+    the other changes neither's reading."""
+    prof, texts = recorded
+    ops = prof.ops[0]
+    lo, hi = ops[0][0], max(o[1] for o in ops)
+    one = scopes.scope_times(prof, texts, lo, hi, ("dot_general",))
+    two = scopes.scope_times(prof, texts, lo, hi, ("div", "dot_general"))
+    assert two["div"] > 0
+    assert {k: two[k] for k in one} == one
+
+
+@pytest.mark.parametrize("extra", [["a/b"], "mlp", [3], ["1x"]])
+def test_a_scope_that_is_no_name_is_refused(extra):
+    with pytest.raises(ValueError, match="no names"):
+        scopes.extra_scopes({"name": "c", "scopes": extra})

@@ -74,3 +74,27 @@ def test_a_released_chip_is_not_held_until_granted():
 
 def test_op_name_drops_the_hlo_text():
     assert trace.op_name("%fusion.12 = bf16[2]{0} fusion(x)") == "fusion.12"
+
+
+def test_breakdown_counts_exclusive_time_and_names_gaps_by_program_span():
+    from bench.scopes import Profile, Span
+    ops = [(0, 100, "while.1"), (10, 30, "a"), (40, 90, "b"),
+           (130, 150, "c")]
+    spans = [("bench.step", 0, 200)]
+    program = [Span("edl.step", 0, 200, {}, "t"),
+               Span("edl.step.post", 100, 125, {}, "t"),
+               Span("edl.adjust.prep", 105, 160, {"adj": 1}, "worker")]
+    red = trace.Reduced(ops={0: ops}, spans=spans,
+                        program=Profile({0: ops}, {}, program))
+    run = types.SimpleNamespace(reduced=red, held=[(0.0, (0,))])
+    out = trace.breakdown(run, 0, 200)
+    # the while's own time is 100 less its body's 20 + 50
+    assert out["device_ops"] == [["b", 50e-9], ["while.1", 30e-9],
+                                 ["a", 20e-9], ["c", 20e-9]]
+    # gaps [150, 200), [100, 130): the innermost program span in the
+    # middle of each, else the benchmark's
+    assert out["idle_gaps"] == [["chip 0: edl.step", 50e-9],
+                                ["chip 0: edl.adjust.prep", 30e-9]]
+    red.program = None
+    assert trace.breakdown(run, 0, 200)["idle_gaps"][0] == \
+        ["chip 0: bench.step", 50e-9]

@@ -69,33 +69,76 @@ def reference(config: dict):
     return for_config(config)
 
 
-# program attribute <- configuration key, for the widths every decoder has
-WIDTHS = {"d_model": "hidden_size", "d_ff": "intermediate_size",
-          "n_heads": "num_attention_heads",
-          "n_kv_heads": "num_key_value_heads",
-          "resolved_head_dim": "head_dim",
-          "tie_embeddings": "tie_word_embeddings"}
+_ABSENT = type("Absent", (), {"__repr__": lambda self: "absent"})()
+
+
+def _get(obj, path: str):
+    """The program configuration's field at a dotted path ("moe.top_k");
+    ``_ABSENT`` where a block on the way is None."""
+    for name in path.split("."):
+        if obj is None:
+            return _ABSENT
+        obj = getattr(obj, name)
+    return obj
+
+
+def _replace(obj, values: dict):
+    """``obj`` with the fields at the dotted paths of ``values`` replaced,
+    inside nested blocks too."""
+    top, nested = {}, {}
+    for path, v in values.items():
+        head, _, rest = path.partition(".")
+        if rest:
+            nested.setdefault(head, {})[rest] = v
+        else:
+            top[path] = v
+    for head, sub in nested.items():
+        block = getattr(obj, head)
+        if block is None:
+            raise ValueError(f"program config {obj.name} has no {head} to "
+                             f"set {sorted(sub)} in")
+        top[head] = _replace(block, sub)
+    return dataclasses.replace(obj, **top)
+
+
+def _flat(d: dict, prefix: str = "") -> dict:
+    """A file's nested dicts as dotted paths; a null block stays whole."""
+    out = {}
+    for k, v in d.items():
+        if isinstance(v, dict):
+            out.update(_flat(v, f"{prefix}{k}."))
+        else:
+            out[prefix + k] = v
+    return out
 
 
 def program_config(config: dict):
     """The program's ArchConfig for a configuration file: the program's
     own published configuration with the file's cuts and dtypes, checked
     width by width against the file, and against the architecture that the
-    file's ``program_arch`` states."""
+    file's ``program_arch`` states, field by field into nested blocks
+    (``"moe": {...}``).
+
+    The reference module's ``APPLIED`` maps the program fields that the
+    file sets, dotted into nested blocks ("moe.n_experts"), to the file's
+    keys; its ``WIDTHS`` maps the fields that are checked. A field in both
+    would be checked against the value just set from the same key, so a
+    module that names one is refused."""
+    ref = reference(config)
+    both = sorted(set(ref.APPLIED) & set(ref.WIDTHS))
+    if both:
+        raise ValueError(f"reference module {ref.__name__} both sets and "
+                         f"checks {both}")
     module, _, attr = config["program_config"].partition(":")
     base = getattr(importlib.import_module(module), attr or "CONFIG")
-    cfg = dataclasses.replace(
-        base, n_layers=config["num_hidden_layers"],
-        vocab=config["vocab_size"], param_dtype=config["param_dtype"],
-        compute_dtype=config["compute_dtype"],
-        norm_eps=config["rms_norm_eps"], rope_theta=config["rope_theta"],
-        remat=config["remat"])
-    want = {k: config[v] for k, v in WIDTHS.items()}
-    want.update(config["program_arch"])
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"program config {cfg.name} differs from "
-                         f"{config['name']}: {got} != {want}")
+    cfg = _replace(base, {path: config[key]
+                          for path, key in ref.APPLIED.items()})
+    widths = {path: config[key] for path, key in ref.WIDTHS.items()}
+    for want in (widths, _flat(config["program_arch"])):
+        got = {k: _get(cfg, k) for k in want}
+        if got != want:
+            raise ValueError(f"program config {cfg.name} differs from "
+                             f"{config['name']}: {got} != {want}")
     return cfg
 
 
@@ -349,8 +392,12 @@ class Driver:
 
     def free(self):
         """Drop the trainer's state and programs; the sample ids and the
-        check's readings stay."""
+        check's readings stay. While tracing, each step program's HLO text
+        goes to ``run.hlo_texts`` first."""
         tr = self.trainer
+        if self.run.trace:
+            self.run.hlo_texts = [h.step_fn.as_text()
+                                  for h in tr._exec_cache.values()]
         tr.state = None
         tr.exec = None
         tr._exec_cache.clear()
